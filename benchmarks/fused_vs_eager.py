@@ -76,6 +76,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.kernels.common import interpret_default
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import (forward_train, init_params, prefill, resolve_plan,
                           supports_chunked_prefill, supports_speculative)
 from repro.serving import ServingEngine
@@ -644,6 +645,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="BENCH_fused.json")
     ap.add_argument("--archs", default=",".join(ARCHS))
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report: Dict[str, Any] = {
         "backend": jax.default_backend(),

@@ -76,10 +76,10 @@ def check_pools(cfg: ModelConfig, cache_defs,
             if kind != "kv":
                 continue
             shape = tuple(cd.shape)
-            if len(shape) == 5 and shape[2] != page_size:
+            if len(shape) == 5 and shape[3] != page_size:
                 diags.append(Diagnostic(
                     "error", "effects", where, "page-granule-mismatch",
-                    f"pool {name} has page granule {shape[2]} but the "
+                    f"pool {name} has page granule {shape[3]} but the "
                     f"plan streams {page_size}-token pages",
                     "build pools and plan from one page_size"))
             if not kv_quant:

@@ -1,4 +1,4 @@
-"""Pass 2 — kernel lint: block legality, VMEM budgets, prefetch arity.
+"""Pass 2 — kernel lint: block legality, VMEM budgets, quant mode.
 
 Checks every fused ``KernelChoice`` against the model dimensions and the
 platform model in ``core/platforms.py`` WITHOUT tracing a kernel:
@@ -12,11 +12,9 @@ platform model in ``core/platforms.py`` WITHOUT tracing a kernel:
   * a per-kernel VMEM footprint estimate (operand blocks resident per
     grid step, f32 accumulators, w8 scale rows) must fit the platform's
     on-chip memory;
-  * the paged / verify kernels' scalar-prefetch operand arity must agree
-    with the plan's quant mode (quantized pools ride two extra scale
-    operands next to the page table), and the plan's recorded quant mode
-    must agree with the config it is verified against — a cached plan
-    from a different QuantMode would pick wrong kernel twins.
+  * the plan's recorded quant mode must agree with the config it is
+    verified against — a cached plan from a different QuantMode would
+    pick wrong kernel twins.
 """
 
 from __future__ import annotations
@@ -47,17 +45,6 @@ KNOWN_KERNELS: Dict[str, Tuple[str, ...]] = {
     "rwkv6_wkv": ("chunk",),
     "streamed_xent": ("block_t", "block_v"),
 }
-
-# Scalar-prefetch operand arity: (without, with) quantized KV pools.
-# paged: lengths + page_table (+ k/v page scales); verify: q_off +
-# page_table (+ scales); the chunked flash kernel packs its metadata
-# into ONE prefetch vector and takes scales as regular operands.
-SCALAR_PREFETCH: Dict[str, Tuple[int, int]] = {
-    "paged_attention": (2, 4),
-    "verify_attention": (2, 4),
-    "flash_attention": (1, 1),
-}
-
 
 def _feature_blocks(cfg: ModelConfig, stage: str, choice: KernelChoice,
                     kv_len: int) -> List[Tuple[str, int]]:
@@ -165,7 +152,6 @@ def check_kernels(plan: StreamPlan, cfg: ModelConfig,
             "rebuild the plan with the config's quant mode "
             "(plans are cached per config)"))
 
-    kv_quant = cfg.kv_quant is not None
     for kind, stage, choice in plan.stage_choices():
         if not choice.fused:
             continue
@@ -245,18 +231,4 @@ def check_kernels(plan: StreamPlan, cfg: ModelConfig,
                     "grid step — over half the on-chip budget, leaving "
                     "no room for double-buffering",
                     "shrink the stage's block targets"))
-
-        # Scalar-prefetch operand arity for the paged/verify/chunk path.
-        if impl in SCALAR_PREFETCH:
-            base, quant_arity = SCALAR_PREFETCH[impl]
-            expect = quant_arity if kv_quant else base
-            have = quant_arity if plan.quant in ("kv_int8", "kv_fp8",
-                                                 "w8_kv8") else base
-            if impl != "flash_attention" and have != expect:
-                diags.append(Diagnostic(
-                    "error", "kernel", where, "prefetch-arity",
-                    f"{impl} would prefetch {have} scalar operands under "
-                    f"plan quant {plan.quant!r} but the config's pools "
-                    f"need {expect} (page table ± per-page scales)",
-                    "rebuild the plan under the config's quant mode"))
     return diags

@@ -109,18 +109,14 @@ def verify_or_raise(plan: StreamPlan, cfg: ModelConfig, mesh=None,
 _QUANT_ALL = ("none", "kv_int8", "w8_kv8")
 
 
-def _abstract_mesh(axes: Tuple[Tuple[str, int], ...]):
+def _mesh_for(devices: int):
     """A deviceless mesh carrying only axis names + sizes."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh(axes)
-
-
-def _mesh_for(devices: int):
     if devices <= 1:
         return None
     if devices % 2 == 0 and devices > 2:
-        return _abstract_mesh((("data", 2), ("model", devices // 2)))
-    return _abstract_mesh((("model", devices),))
+        return AbstractMesh((2, devices // 2), ("data", "model"))
+    return AbstractMesh((devices,), ("model",))
 
 
 def main(argv: Optional[List[str]] = None) -> int:

@@ -1,4 +1,4 @@
-"""Active-mesh context + version-tolerant ``shard_map``.
+"""Active-mesh context + the ``shard_map`` the fused wrappers use.
 
 The mesh-aware StreamPlan (core/stream_plan.py) decides *which* mesh axes
 each fused kernel's block grid shards over; the fused wrappers in
@@ -20,10 +20,7 @@ from contextlib import contextmanager
 from contextvars import ContextVar
 from typing import Iterator, Optional
 
-try:                                    # jax >= 0.6 top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                     # older jax: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh
 
 _ACTIVE_MESH: ContextVar[Optional[Mesh]] = ContextVar(
@@ -48,11 +45,7 @@ def use_mesh(mesh: Optional[Mesh]) -> Iterator[Optional[Mesh]]:
 
 
 def shard_map(body, *, mesh, in_specs, out_specs):
-    """Version-tolerant shard_map: replication checking is named
-    ``check_vma`` on new jax and ``check_rep`` before the rename."""
-    try:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with varying-manual-axes checking off: the
+    Pallas kernels inside carry no replication annotations."""
+    return _shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)

@@ -154,9 +154,13 @@ def _flash_kernel_offset_q(meta_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                            causal: bool, window: int):
     """Quantized twin of ``_flash_kernel_offset`` (DESIGN.md §14): K/V
     blocks are int8/fp8 codes dequantized in-register against per-POSITION
-    f32 scales (``[Hkv_, Skv]`` operands blocked alongside K/V — each KV
-    position inherits its page's per-(page, head) scale, expanded by the
-    gather wrapper).  Math stays f32; masking/skips are unchanged."""
+    f32 scales (``[Hkv_, 1, Skv]`` operands blocked alongside K/V — each
+    KV position inherits its page's per-(page, head) scale, expanded by
+    the gather wrapper).  A position's scale is a column scale of the
+    score tile and a row scale of V, so both apply as a lane-major
+    ``[1, bkv]`` row — ``q @ (codes * s)^T == (q @ codes^T) * s`` and
+    ``p @ (codes * s) == (p * s) @ codes`` — with no in-kernel transpose.
+    Math stays f32; masking/skips are unchanged."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     q_off = meta_ref[0]
@@ -178,11 +182,12 @@ def _flash_kernel_offset_q(meta_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0]
-        k = k_ref[0].astype(jnp.float32) * ks_ref[0][:, None]
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [bq, bkv]
+            preferred_element_type=jnp.float32) * (
+                ks_ref[0] * scale)                           # [bq, bkv]
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32,
                                                    (block_q, block_kv), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32,
@@ -198,9 +203,9 @@ def _flash_kernel_offset_q(meta_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[0] = l_ref[0] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32) * vs_ref[0][:, None]
+        v = v_ref[0].astype(jnp.float32)
         acc_ref[0] = acc_ref[0] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p * vs_ref[0], v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[0] = m_new
 
@@ -271,7 +276,7 @@ def flash_attention_2d(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
         def sc_block(b, i, j, meta):
             last_live = jnp.maximum(meta[1] - 1, 0) // bkv
-            return (b // g, jnp.minimum(j, last_live))
+            return (b // g, 0, jnp.minimum(j, last_live))
 
         in_specs = [
             pl.BlockSpec((1, bq, d), lambda b, i, j, meta: (b, i, 0)),
@@ -280,10 +285,12 @@ def flash_attention_2d(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ]
         operands = (q, k, v)
         if quant:
-            in_specs += [pl.BlockSpec((1, bkv), sc_block),
-                         pl.BlockSpec((1, bkv), sc_block)]
-            operands += (k_scale.astype(jnp.float32),
-                         v_scale.astype(jnp.float32))
+            # Scales as [Hkv_, 1, Skv]: a (1, bkv) block row is then
+            # full-dim on the sublane axis and lane-aligned.
+            in_specs += [pl.BlockSpec((1, 1, bkv), sc_block),
+                         pl.BlockSpec((1, 1, bkv), sc_block)]
+            operands += (k_scale.astype(jnp.float32)[:, None],
+                         v_scale.astype(jnp.float32)[:, None])
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,           # [q_offset, kv_len]
             grid=grid,

@@ -59,7 +59,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     g = hq // hkv
-    dp = round_up(d, LANE) if not interpret_default() else d
+    interpret = interpret_default() if interpret is None else interpret
+    dp = d if interpret else round_up(d, LANE)
     if dp != d:
         pad = ((0, 0), (0, 0), (0, 0), (0, dp - d))
         q = jnp.pad(q, pad)

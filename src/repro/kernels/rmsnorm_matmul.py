@@ -47,7 +47,7 @@ def _kernel_w8(x_ref, scale_ref, w_ref, ws_ref, o_ref, *, eps: float):
     normed = normed * (1.0 + scale_ref[...].astype(jnp.float32))
     acc = jnp.dot(normed, w_ref[...].astype(jnp.float32),
                   preferred_element_type=jnp.float32)
-    o_ref[...] = (acc * ws_ref[...][None, :]).astype(o_ref.dtype)
+    o_ref[...] = (acc * ws_ref[...]).astype(o_ref.dtype)
 
 
 def rmsnorm_matmul(x: jax.Array, scale: jax.Array, w: jax.Array, *,
@@ -58,7 +58,9 @@ def rmsnorm_matmul(x: jax.Array, scale: jax.Array, w: jax.Array, *,
     """x: [T, D]; scale: [D]; w: [D, N] -> rms_norm(x) @ w  [T, N].
 
     ``w_scale`` [N]: weight-only int8 — ``w`` is int8 codes, dequantized
-    against the per-output-channel scales inside the kernel.
+    against the per-output-channel scales inside the kernel.  The scales
+    ride as a ``[1, N]`` row in ``(1, bn)`` blocks, the lane-aligned 2-D
+    form Mosaic accepts for a partial block.
     """
     t, d = x.shape
     d2, n = w.shape
@@ -75,8 +77,8 @@ def rmsnorm_matmul(x: jax.Array, scale: jax.Array, w: jax.Array, *,
     operands = [x, scale, w]
     kernel = _kernel
     if w_scale is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j: (j,)))
-        operands.append(w_scale.astype(jnp.float32))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, j)))
+        operands.append(w_scale.astype(jnp.float32).reshape(1, n))
         kernel = _kernel_w8
     return pl.pallas_call(
         functools.partial(kernel, eps=eps),

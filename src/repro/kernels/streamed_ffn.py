@@ -54,6 +54,11 @@ def _rms_tile(x, scale_ref, eps: float):
     return y.astype(x.dtype)
 
 
+def _row(scale: jax.Array) -> jax.Array:
+    """Per-channel scales [N] -> the f32 [1, N] row the w8 bodies read."""
+    return scale.astype(jnp.float32).reshape(1, -1)
+
+
 def _ffn_kernel(*refs, n_f: int, activation: str, norm_eps: Optional[float]):
     if norm_eps is not None:
         x_ref, scale_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref = refs
@@ -101,13 +106,13 @@ def _ffn_kernel_w8(*refs, n_f: int, activation: str,
         x = _rms_tile(x, scale_ref, norm_eps)
     x32 = x.astype(jnp.float32)
     gate = jnp.dot(x32, wg_ref[...].astype(jnp.float32),
-                   preferred_element_type=jnp.float32) * wgs_ref[...][None]
+                   preferred_element_type=jnp.float32) * wgs_ref[...]
     up = jnp.dot(x32, wu_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32) * wus_ref[...][None]
+                 preferred_element_type=jnp.float32) * wus_ref[...]
     h = _act(activation, gate) * up                     # stays in VMEM
     acc_ref[...] += jnp.dot(h, wd_ref[...].astype(jnp.float32),
                             preferred_element_type=jnp.float32
-                            ) * wds_ref[...][None]
+                            ) * wds_ref[...]
 
     @pl.when(pl.program_id(1) == n_f - 1)
     def _done():
@@ -128,6 +133,8 @@ def streamed_ffn(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     ``norm_scale`` [D]: fold ``rms_norm(x, norm_scale)`` into the kernel.
     ``wg_scale``/``wu_scale`` [F] + ``wd_scale`` [D]: weight-only int8 —
     the weights are int8 codes dequantized in-kernel per output channel.
+    Scales ride as ``[1, N]`` rows in ``(1, bf)`` / ``(1, D)`` blocks, the
+    lane-aligned 2-D form Mosaic accepts for a partial block.
     """
     t, d = x.shape
     d2, f = wg.shape
@@ -146,15 +153,14 @@ def streamed_ffn(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
     if w8:
         in_specs += [
             pl.BlockSpec((d, bf), lambda i, j: (0, j)),
-            pl.BlockSpec((bf,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bf), lambda i, j: (0, j)),
             pl.BlockSpec((d, bf), lambda i, j: (0, j)),
-            pl.BlockSpec((bf,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bf), lambda i, j: (0, j)),
             pl.BlockSpec((bf, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
+            pl.BlockSpec((1, d), lambda i, j: (0, 0)),
         ]
-        operands += [wg, wg_scale.astype(jnp.float32),
-                     wu, wu_scale.astype(jnp.float32),
-                     wd, wd_scale.astype(jnp.float32)]
+        operands += [wg, _row(wg_scale), wu, _row(wu_scale),
+                     wd, _row(wd_scale)]
         kernel = _ffn_kernel_w8
     else:
         in_specs += [
@@ -220,11 +226,11 @@ def _mlp_kernel_w8(*refs, n_f: int, activation: str,
         x = _rms_tile(x, scale_ref, norm_eps)
     x32 = x.astype(jnp.float32)
     up = jnp.dot(x32, wu_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32) * wus_ref[...][None]
+                 preferred_element_type=jnp.float32) * wus_ref[...]
     h = _act(activation, up)
     acc_ref[...] += jnp.dot(h, wd_ref[...].astype(jnp.float32),
                             preferred_element_type=jnp.float32
-                            ) * wds_ref[...][None]
+                            ) * wds_ref[...]
 
     @pl.when(pl.program_id(1) == n_f - 1)
     def _done():
@@ -259,12 +265,11 @@ def streamed_mlp(x: jax.Array, wu: jax.Array, wd: jax.Array, *,
     if w8:
         in_specs += [
             pl.BlockSpec((d, bf), lambda i, j: (0, j)),
-            pl.BlockSpec((bf,), lambda i, j: (j,)),
+            pl.BlockSpec((1, bf), lambda i, j: (0, j)),
             pl.BlockSpec((bf, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((d,), lambda i, j: (0,)),
+            pl.BlockSpec((1, d), lambda i, j: (0, 0)),
         ]
-        operands += [wu, wu_scale.astype(jnp.float32),
-                     wd, wd_scale.astype(jnp.float32)]
+        operands += [wu, _row(wu_scale), wd, _row(wd_scale)]
         kernel = _mlp_kernel_w8
     else:
         in_specs += [

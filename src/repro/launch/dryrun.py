@@ -1,4 +1,7 @@
 import os
+# Deviceless by design: 512 forced host devices stand in for the pods, and
+# the CPU platform is pinned so the dry-run never claims an attached chip.
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 

@@ -14,13 +14,10 @@ import jax
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Version-tolerant ``jax.make_mesh``: ``axis_types`` (with Auto axes)
-    only exists on newer jax; older releases default every axis to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (sharding propagated by
+    the compiler, as the plan's shard_map claims expect)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

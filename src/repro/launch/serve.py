@@ -2,11 +2,15 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
         [--requests 8] [--prompt-len 32] [--new-tokens 16]
+
+The engine runs the StreamPlan's fused Pallas kernels, the path
+``chip_smoke.py`` checks on the chip.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -16,6 +20,7 @@ import numpy as np
 from ..configs import ARCHS, get_config
 from ..models import init_params
 from ..serving.engine import ServingEngine
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -33,8 +38,9 @@ def main(argv=None) -> int:
     ap.add_argument("--page-size", type=int, default=None,
                     help="KV page size (default: StreamPlan tile / 16)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_config(args.arch)
+    cfg = dataclasses.replace(get_config(args.arch), use_fused_kernels=True)
     if args.smoke:
         cfg = cfg.reduced()
     if cfg.encoder_only:

@@ -174,7 +174,7 @@ def _claim_axis(mesh, shard, dim: str, extent: int):
 
 
 def _smap(fn, mesh, in_specs, out_specs):
-    """shard_map a kernel dispatch (version-tolerant) and record it."""
+    """shard_map a kernel dispatch and record it."""
     from ..distributed.context import shard_map
     DISPATCH_RECORDS["shard_map"] += 1
     return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
@@ -1017,11 +1017,11 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
                                       k_scale=ks, v_scale=vs)
 
     mesh = _shard_mesh(shard)
-    hax = _claim_axis(mesh, shard, "kv_heads", k_pool.shape[2])
+    hax = _claim_axis(mesh, shard, "kv_heads", k_pool.shape[1])
     bax = _claim_axis(mesh, shard, "batch", q.shape[0])
     if hax or bax:
-        in_specs = (P(bax, None, hax, None), P(None, None, hax, None),
-                    P(None, None, hax, None), P(bax, None), P(bax))
+        in_specs = (P(bax, None, hax, None), P(None, hax, None, None),
+                    P(None, hax, None, None), P(bax, None), P(bax))
         if quant:
             in_specs += (P(None, hax), P(None, hax))
         call = _smap(call, mesh, in_specs, P(bax, None, hax, None))
@@ -1054,11 +1054,11 @@ def fused_verify_attention(q: jax.Array, k_pool: jax.Array,
                                       k_scale=ks, v_scale=vs)
 
     mesh = _shard_mesh(shard)
-    hax = _claim_axis(mesh, shard, "kv_heads", k_pool.shape[2])
+    hax = _claim_axis(mesh, shard, "kv_heads", k_pool.shape[1])
     bax = _claim_axis(mesh, shard, "batch", q.shape[0])
     if hax or bax:
-        in_specs = (P(bax, None, hax, None), P(None, None, hax, None),
-                    P(None, None, hax, None), P(bax, None), P(bax))
+        in_specs = (P(bax, None, hax, None), P(None, hax, None, None),
+                    P(None, hax, None, None), P(bax, None), P(bax))
         if quant:
             in_specs += (P(None, hax), P(None, hax))
         call = _smap(call, mesh, in_specs, P(bax, None, hax, None))

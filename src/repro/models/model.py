@@ -67,7 +67,7 @@ def _cache_kv_len(cfg: ModelConfig, cache: Tree,
     """Max KV length held by a decode cache (None for pure SSM caches).
 
     Stacked K leaves are [G, B, S, Hkv, hd] ("bshd") or [G, B, Hkv, S, hd]
-    ("bhsd"); paged K leaves are pools [G, P, page_size, Hkv, hd] and the
+    ("bhsd"); paged K leaves are pools [G, P, Hkv, page_size, hd] and the
     extent is the page table's ``max_pages * page_size``.  Used so the
     decode plan's DSE models attention over the real cache extent rather
     than the (tiny) per-step token count.
@@ -76,7 +76,7 @@ def _cache_kv_len(cfg: ModelConfig, cache: Tree,
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         if cache_leaf_kind(cache_leaf_name(path)) == "kv":
             if page_table is not None:
-                return int(page_table.shape[1]) * int(leaf.shape[2])
+                return int(page_table.shape[1]) * int(leaf.shape[3])
             return int(leaf.shape[kv_seq_axis(cfg.kv_cache_layout)])
     return None
 
@@ -524,7 +524,7 @@ def _attn_block_chunk(cfg: ModelConfig, p: Tree, x: jax.Array, cache: Tree,
                       ) -> Tuple[jax.Array, Tree]:
     """One attention block over a prompt CHUNK, against the paged cache.
 
-    x: [1, C, D]; cache: {"k","v"} pools [P, page_size, Hkv, hd];
+    x: [1, C, D]; cache: {"k","v"} pools [P, Hkv, page_size, hd];
     table_row: [max_pages] the slot's logical->physical page map;
     chunk_pages: [C // page_size] physical pages of THIS chunk;
     offset: dynamic chunk start position; kv_len: dynamic valid KV extent
@@ -579,7 +579,7 @@ def _attn_block_chunk(cfg: ModelConfig, p: Tree, x: jax.Array, cache: Tree,
     # Bound KV traffic by the live prefix: the gather touches O(prefix)
     # distinct pages instead of the slot's full table extent (masking at
     # kv_len already discards the dead rows' scores).
-    row_live = live_page_table(table_row, kv_len, cache["k"].shape[1])
+    row_live = live_page_table(table_row, kv_len, cache["k"].shape[2])
     choice = lplan.attention if lplan is not None else None
     fused = choice is not None and choice.fused
     if quant and not fused:
@@ -602,7 +602,7 @@ def _attn_block_chunk(cfg: ModelConfig, p: Tree, x: jax.Array, cache: Tree,
         # per-position scale lanes the kernel consumes next to each tile.
         scl = {}
         if quant:
-            ps_ = cache["k"].shape[1]
+            ps_ = cache["k"].shape[2]
             scl = {"k_scale": jnp.repeat(ks[row_live], ps_, axis=0)[None],
                    "v_scale": jnp.repeat(vs[row_live], ps_, axis=0)[None]}
         o = L.fused_attention_chunk(q, kseq, vseq, offset, kv_len,
@@ -729,11 +729,12 @@ def prefill_chunk(params: Tree, cfg: ModelConfig, tokens: jax.Array,
 
 
 def _cache_page_size(cache: Tree) -> int:
-    """Page size of a paged cache tree (shape[2] of any K/V pool leaf)."""
+    """Page size of a paged cache tree (shape[3] of any stacked K/V pool
+    leaf [G, P, Hkv, page_size, hd])."""
     from .params import cache_leaf_kind, cache_leaf_name
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         if cache_leaf_kind(cache_leaf_name(path)) == "kv":
-            return int(leaf.shape[2])
+            return int(leaf.shape[3])
     raise ValueError("cache tree holds no K/V pool leaves")
 
 
@@ -756,7 +757,7 @@ def _attn_block_decode(cfg: ModelConfig, p: Tree, x: jax.Array,
                        page_table: Optional[jax.Array] = None,
                        ) -> Tuple[jax.Array, Tree]:
     """x: [B,1,D]; cache: {"k","v"} [B,Smax,Hkv,hd] contiguous, or paged
-    pools [P,page_size,Hkv,hd] when ``page_table`` ([B,max_pages]) is set.
+    pools [P,Hkv,page_size,hd] when ``page_table`` ([B,max_pages]) is set.
 
     ``cache_pos`` may be a scalar or a per-slot [B] vector.  With a page
     table the token is scattered through the slot's page indirection and
@@ -815,7 +816,7 @@ def _attn_block_decode(cfg: ModelConfig, p: Tree, x: jax.Array,
             # Bound the gather by each slot's live prefix, mirroring the
             # chunk path (the length mask already discards dead rows).
             tbl_live = live_page_table(page_table, lengths + 1,
-                                       cache["k"].shape[1])
+                                       cache["k"].shape[2])
             if quant:
                 kd = gather_pages_dequant(kc, ks, tbl_live, layout=layout)
                 vd = gather_pages_dequant(vc, vs, tbl_live, layout=layout)
@@ -1081,7 +1082,7 @@ def _attn_block_verify(cfg: ModelConfig, p: Tree, x: jax.Array,
                                      shard=choice.sharding)
     else:
         tbl_live = live_page_table(page_table, lengths + w,
-                                   cache["k"].shape[1])
+                                   cache["k"].shape[2])
         if quant:
             kd = gather_pages_dequant(kc, ks, tbl_live, layout=layout)
             vd = gather_pages_dequant(vc, vs, tbl_live, layout=layout)

@@ -4,9 +4,11 @@ The contiguous slot cache (PR 1) reserves ``slots x max_len`` worst-case
 K/V per layer group.  This module pages it, vLLM/TensorRT-LLM style:
 
   * K/V storage is a *pool* of fixed-size pages per layer group, stored
-    page-major and layout-canonical: ``[G, P, page_size, Hkv, hd]``
-    regardless of the model's ``kv_cache_layout`` (append/gather adapt at
-    the edges, so both "bshd" and "bhsd" configs run paged).
+    page-major and head-major within a page: ``[G, P, Hkv, page_size,
+    hd]`` regardless of the model's ``kv_cache_layout`` (append/gather
+    adapt at the edges, so both "bshd" and "bhsd" configs run paged).
+    One (page, kv head) is then a dense ``[page_size, hd]`` tile — the
+    block the paged attention kernel streams.
   * A device-resident page table ``[slots, max_pages] int32`` maps each
     slot's logical page j to a physical page id.  Physical page 0 is the
     NULL page: unallocated table entries point at it, so inactive slots'
@@ -86,8 +88,9 @@ def cdiv(a: int, b: int) -> int:
 # Functional primitives (jit-safe, layout-adapting)
 # --------------------------------------------------------------------- #
 
-def to_page_major(seq: jax.Array, layout: str) -> jax.Array:
-    """K/V with a batch axis -> canonical [..., S, H, hd] order.
+def to_seq_major(seq: jax.Array, layout: str) -> jax.Array:
+    """K/V with a batch axis -> token-major [..., S, H, hd] order (the
+    order per-token appends scatter in).
 
     seq: [B, S, H, hd] ("bshd") or [B, H, S, hd] ("bhsd").
     """
@@ -96,11 +99,22 @@ def to_page_major(seq: jax.Array, layout: str) -> jax.Array:
     return seq
 
 
-def from_page_major(seq: jax.Array, layout: str) -> jax.Array:
-    """Inverse of ``to_page_major``."""
+def to_pages(seq: jax.Array, page_size: int) -> jax.Array:
+    """Token-major [..., S, H, hd] (S a multiple of ``page_size``) ->
+    pool pages [..., S // page_size, H, page_size, hd]."""
+    *lead, s, h, hd = seq.shape
+    pages = seq.reshape(*lead, s // page_size, page_size, h, hd)
+    return jnp.swapaxes(pages, -3, -2)
+
+
+def from_pages(pages: jax.Array, layout: str) -> jax.Array:
+    """Gathered pool pages [B, n, H, page_size, hd] -> contiguous K/V in
+    the model's layout: [B, n*page_size, H, hd] ("bshd") or [B, H,
+    n*page_size, hd] ("bhsd")."""
+    b, n, h, ps, hd = pages.shape
     if layout == "bhsd":
-        return jnp.swapaxes(seq, -3, -2)
-    return seq
+        return pages.transpose(0, 2, 1, 3, 4).reshape(b, h, n * ps, hd)
+    return pages.transpose(0, 1, 3, 2, 4).reshape(b, n * ps, h, hd)
 
 
 # --------------------------------------------------------------------- #
@@ -110,8 +124,8 @@ def from_page_major(seq: jax.Array, layout: str) -> jax.Array:
 # Quantized pools store CODES: ``value ≈ code * scale`` with one f32 scale
 # per (physical page, kv head) riding in a scale pool ``[G, num_pages,
 # Hkv]`` next to each value pool.  Scales are per-page so the paged
-# kernels can fetch them through the same scalar-prefetch page-table
-# indirection as the pages themselves, and per-kv-head because head norms
+# kernels can fetch them through the same page-table indirection as the
+# pages themselves, and per-kv-head because head norms
 # differ by orders of magnitude while positions within a page do not.
 
 def kv_quant_dtype(kind: Optional[str]):
@@ -163,7 +177,7 @@ def cow_copy_pool(pool: jax.Array, src: jax.Array,
                   dst: jax.Array) -> jax.Array:
     """Copy physical page(s) ``src`` onto ``dst`` inside a pool.
 
-    pool: [P, page_size, H, hd]; src/dst: int32 scalars or [N] vectors of
+    pool: [P, H, page_size, hd]; src/dst: int32 scalars or [N] vectors of
     physical page ids.  The copy-on-write primitive: a shared page is
     duplicated into a freshly allocated one *before* the first divergent
     write, so the writer mutates its private copy and every other
@@ -181,7 +195,7 @@ def paged_append(pool: jax.Array, page_table: jax.Array, pos: jax.Array,
                  cow_dst: Optional[jax.Array] = None) -> jax.Array:
     """Scatter one decode token per slot into its page.
 
-    pool: [P, page_size, H, hd]; page_table: [B, max_pages] int32;
+    pool: [P, H, page_size, hd]; page_table: [B, max_pages] int32;
     pos: [B] absolute write positions; new: [B, 1, H, hd] ("bshd") or
     [B, H, 1, hd] ("bhsd").  Unallocated table entries resolve to the NULL
     page, and a position at/past the table's extent routes to the NULL
@@ -199,18 +213,18 @@ def paged_append(pool: jax.Array, page_table: jax.Array, pos: jax.Array,
     and ``page_table`` must already point at ``cow_dst`` so the write —
     and every later read — resolves to the private copy.
     """
-    page_size = pool.shape[1]
+    page_size = pool.shape[2]
     b = page_table.shape[0]
     if cow_src is not None:
         pool = cow_copy_pool(pool, cow_src, cow_dst)
-    tok = to_page_major(new, layout)[:, 0]                 # [B, H, hd]
+    tok = to_seq_major(new, layout)[:, 0]                  # [B, H, hd]
     extent = page_table.shape[1] * page_size
     in_range = jnp.logical_and(pos >= 0, pos < extent)
     posc = jnp.clip(pos, 0, extent - 1)
     phys = jnp.where(in_range,
                      page_table[jnp.arange(b), posc // page_size],
                      NULL_PAGE)                            # [B]
-    return pool.at[phys, posc % page_size].set(tok.astype(pool.dtype))
+    return pool.at[phys, :, posc % page_size].set(tok.astype(pool.dtype))
 
 
 def _append_row_q(pool: jax.Array, scale: jax.Array,
@@ -218,7 +232,7 @@ def _append_row_q(pool: jax.Array, scale: jax.Array,
                   tok: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Quantize-on-write core: one page-major token row per slot.
 
-    pool: [P, page_size, H, hd] codes; scale: [P, H] f32; tok: [B, H, hd]
+    pool: [P, H, page_size, hd] codes; scale: [P, H] f32; tok: [B, H, hd]
     full-precision.  Per-page scales are MONOTONE non-decreasing: the new
     scale is ``max(old, amax(tok)/qmax)``, and when it grows the page's
     existing rows are re-encoded at the new scale in the same scatter
@@ -226,7 +240,7 @@ def _append_row_q(pool: jax.Array, scale: jax.Array,
     matches ``paged_append``: out-of-range positions write the
     sacrificial page's codes and scale, which nothing dequantizes.
     """
-    page_size = pool.shape[1]
+    page_size = pool.shape[2]
     b = page_table.shape[0]
     extent = page_table.shape[1] * page_size
     in_range = jnp.logical_and(pos >= 0, pos < extent)
@@ -238,11 +252,11 @@ def _append_row_q(pool: jax.Array, scale: jax.Array,
     amax = jnp.max(jnp.abs(tok.astype(jnp.float32)), axis=-1)   # [B, H]
     old = scale[phys]                                           # [B, H]
     new = jnp.maximum(old, amax / qmax)
-    page = _requant_codes(pool[phys], old[:, None, :, None],
-                          new[:, None, :, None])     # [B, ps, H, hd]
+    page = _requant_codes(pool[phys], old[:, :, None, None],
+                          new[:, :, None, None])     # [B, H, ps, hd]
     row = quantize_kv(tok, new[..., None], pool.dtype)
     pool = pool.at[phys].set(page)
-    pool = pool.at[phys, posc % page_size].set(row)
+    pool = pool.at[phys, :, posc % page_size].set(row)
     return pool, scale.at[phys].set(new)
 
 
@@ -259,7 +273,7 @@ def paged_append_q(pool: jax.Array, scale: jax.Array,
     if cow_src is not None:
         pool = cow_copy_pool(pool, cow_src, cow_dst)
         scale = cow_copy_pool(scale, cow_src, cow_dst)
-    tok = to_page_major(new, layout)[:, 0]                 # [B, H, hd]
+    tok = to_seq_major(new, layout)[:, 0]                  # [B, H, hd]
     return _append_row_q(pool, scale, page_table, pos, tok)
 
 
@@ -285,11 +299,11 @@ def paged_append_window(pool: jax.Array, page_table: jax.Array,
     engine rolls the slot's extent back (``rollback_extent``) and later
     writes overwrite them; reads in between are masked by ``lengths``.
     """
-    page_size = pool.shape[1]
+    page_size = pool.shape[2]
     b = page_table.shape[0]
     if cow_src is not None:
         pool = cow_copy_pool(pool, cow_src, cow_dst)
-    win = to_page_major(new, layout)                       # [B, W, H, hd]
+    win = to_seq_major(new, layout)                        # [B, W, H, hd]
     w = win.shape[1]
     extent = page_table.shape[1] * page_size
     p = pos[:, None] + jnp.arange(w)[None, :]              # [B, W]
@@ -299,7 +313,7 @@ def paged_append_window(pool: jax.Array, page_table: jax.Array,
         in_range,
         page_table[jnp.arange(b)[:, None], pc // page_size],
         NULL_PAGE)                                         # [B, W]
-    return pool.at[phys, pc % page_size].set(win.astype(pool.dtype))
+    return pool.at[phys, :, pc % page_size].set(win.astype(pool.dtype))
 
 
 def paged_append_window_q(pool: jax.Array, scale: jax.Array,
@@ -315,7 +329,7 @@ def paged_append_window_q(pool: jax.Array, scale: jax.Array,
     if cow_src is not None:
         pool = cow_copy_pool(pool, cow_src, cow_dst)
         scale = cow_copy_pool(scale, cow_src, cow_dst)
-    win = to_page_major(new, layout)                       # [B, W, H, hd]
+    win = to_seq_major(new, layout)                        # [B, W, H, hd]
     for i in range(win.shape[1]):
         pool, scale = _append_row_q(pool, scale, page_table, pos + i,
                                     win[:, i])
@@ -332,18 +346,17 @@ def place_chunk_pages_q(pool: jax.Array, scale: jax.Array, seq: jax.Array,
     head) — chunk placement always overwrites whole pages, so the scale
     is SET, not folded; later decode appends into a partial last page go
     through the monotone ``paged_append_q`` update."""
-    page_size = pool.shape[1]
+    page_size = pool.shape[2]
     if cow_src is not None:
         pool = cow_copy_pool(pool, cow_src, cow_dst)
         scale = cow_copy_pool(scale, cow_src, cow_dst)
-    x = to_page_major(seq, layout)[0]                      # [C, H, hd]
-    c, h, hd = x.shape
-    chunks = x.reshape(c // page_size, page_size, h, hd)
+    chunks = to_pages(to_seq_major(seq, layout)[0],
+                      page_size)                           # [n_cp, H, ps, hd]
     qmax = kv_quant_qmax(pool.dtype)
     amax = jnp.max(jnp.abs(chunks.astype(jnp.float32)),
-                   axis=(1, 3))                            # [n_cp, H]
+                   axis=(2, 3))                            # [n_cp, H]
     new = amax / qmax
-    codes = quantize_kv(chunks, new[:, None, :, None], pool.dtype)
+    codes = quantize_kv(chunks, new[:, :, None, None], pool.dtype)
     return (pool.at[chunk_pages].set(codes),
             scale.at[chunk_pages].set(new))
 
@@ -354,11 +367,9 @@ def gather_pages_dequant(pool: jax.Array, scale: jax.Array,
     """Quantized twin of ``gather_pages``: materialize dense f32 K/V by
     dequantizing each gathered page with its per-(page, head) scale —
     the eager reference the quantized Pallas kernels must match."""
-    pages = pool[page_table].astype(jnp.float32)  # [B, n, ps, H, hd]
+    pages = pool[page_table].astype(jnp.float32)  # [B, n, H, ps, hd]
     s = scale[page_table]                         # [B, n, H]
-    pages = pages * s[:, :, None, :, None]
-    b, n, ps, h, hd = pages.shape
-    return from_page_major(pages.reshape(b, n * ps, h, hd), layout)
+    return from_pages(pages * s[..., None, None], layout)
 
 
 def live_page_table(page_table: jax.Array, lengths, page_size: int
@@ -386,14 +397,12 @@ def gather_pages(pool: jax.Array, page_table: jax.Array, *,
                  layout: str) -> jax.Array:
     """Materialize per-slot contiguous K/V from the pool (reference path).
 
-    pool: [P, page_size, H, hd] -> [B, max_pages*page_size, H, hd]
+    pool: [P, H, page_size, hd] -> [B, max_pages*page_size, H, hd]
     ("bshd") or [B, H, S, hd] ("bhsd").  Entries past a slot's length read
     whatever its (or the NULL) pages hold; callers mask by length exactly
     as with the contiguous cache.
     """
-    pages = pool[page_table]                      # [B, max_pages, ps, H, hd]
-    b, n, ps, h, hd = pages.shape
-    return from_page_major(pages.reshape(b, n * ps, h, hd), layout)
+    return from_pages(pool[page_table], layout)
 
 
 def place_prefill(cache: Tree, fresh: Tree, slot: jax.Array,
@@ -415,7 +424,7 @@ def place_prefill(cache: Tree, fresh: Tree, slot: jax.Array,
     page_size = None
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         if cache_leaf_kind(cache_leaf_name(path)) == "kv":
-            page_size = leaf.shape[2]
+            page_size = leaf.shape[3]
             break
 
     def place_dict(cd: dict, fd: dict) -> dict:
@@ -427,20 +436,18 @@ def place_prefill(cache: Tree, fresh: Tree, slot: jax.Array,
                 out[name] = pool.at[:, slot].set(
                     small[:, 0].astype(pool.dtype))
                 continue
-            seq = to_page_major(small, layout)[:, 0]       # [G, S, H, hd]
-            g, s, h, hd = seq.shape
-            n = pages.shape[0]
-            pad = n * page_size - s
+            seq = to_seq_major(small, layout)[:, 0]        # [G, S, H, hd]
+            pad = pages.shape[0] * page_size - seq.shape[1]
             if pad:
                 seq = jnp.pad(seq, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            chunks = seq.reshape(g, n, page_size, h, hd)
+            chunks = to_pages(seq, page_size)              # [G, n, H, ps, hd]
             sname = name + "_scale"
             if sname in cd:
                 qmax = kv_quant_qmax(pool.dtype)
                 amax = jnp.max(jnp.abs(chunks.astype(jnp.float32)),
-                               axis=(2, 4))                # [G, n, H]
+                               axis=(3, 4))                # [G, n, H]
                 new = amax / qmax
-                codes = quantize_kv(chunks, new[:, :, None, :, None],
+                codes = quantize_kv(chunks, new[..., None, None],
                                     pool.dtype)
                 out[name] = pool.at[:, pages].set(codes)
                 out[sname] = cd[sname].at[:, pages].set(new)
@@ -463,7 +470,7 @@ def place_chunk_pages(pool: jax.Array, seq: jax.Array,
     """Page-aligned incremental prefill placement: write ONE chunk's K/V
     into its physical pages.
 
-    pool: [P, page_size, H, hd]; seq: a batch-1 chunk [1, C, H, hd]
+    pool: [P, H, page_size, hd]; seq: a batch-1 chunk [1, C, H, hd]
     ("bshd") or [1, H, C, hd] ("bhsd"); chunk_pages: [C // page_size]
     int32 physical page ids for the chunk's logical pages.  The chunk size
     is a whole multiple of the page size by construction (the engine
@@ -481,12 +488,10 @@ def place_chunk_pages(pool: jax.Array, seq: jax.Array,
     the state machine uniform — a shared page is never a scatter target;
     ``chunk_pages`` must already carry ``cow_dst``.
     """
-    page_size = pool.shape[1]
+    page_size = pool.shape[2]
     if cow_src is not None:
         pool = cow_copy_pool(pool, cow_src, cow_dst)
-    x = to_page_major(seq, layout)[0]                      # [C, H, hd]
-    c, h, hd = x.shape
-    chunks = x.reshape(c // page_size, page_size, h, hd)
+    chunks = to_pages(to_seq_major(seq, layout)[0], page_size)
     return pool.at[chunk_pages].set(chunks.astype(pool.dtype))
 
 
@@ -541,8 +546,8 @@ def paged_cache_defs(cfg: ModelConfig, slots: int, max_len: int,
                 continue
             groups = cd.shape[0]
             out[name] = CacheDef(
-                (groups, num_pages, page_size, hkv, hd),
-                ("layers", "kv_pages", None, "kv_heads", None),
+                (groups, num_pages, hkv, page_size, hd),
+                ("layers", "kv_pages", "kv_heads", None, None),
                 qdtype if qdtype is not None else cd.dtype)
             if qdtype is not None:
                 out[name + "_scale"] = CacheDef(
@@ -972,8 +977,7 @@ class PagedKVCache:
                     continue
                 assert sname in group, f"missing scale pool {sname}"
                 scd = group[sname]
-                assert scd.shape == (cd.shape[0], cd.shape[1],
-                                     cd.shape[3]), \
+                assert scd.shape == cd.shape[:3], \
                     (f"{sname} shape {scd.shape} out of lockstep with "
                      f"{name} {cd.shape}")
                 assert jnp.dtype(scd.dtype) == jnp.float32

@@ -1,4 +1,4 @@
-"""Measurement harness — wall-clock candidate timing with analytic fallback.
+"""Measurement harness — wall-clock candidate timing, analytic off-chip.
 
 One candidate = one fused ``KernelChoice`` (implementation + block
 targets) at one op-shape context.  ``measure_candidate`` returns the
@@ -11,9 +11,11 @@ number:
     config's own dimensions (``source="measured"``).
   * In interpret mode (deviceless CI) wall-clock would time the Python
     Pallas interpreter, which says nothing about the MXU — so the
-    harness falls back to ``analytic_estimate``, a block-sensitive
+    harness scores with ``analytic_estimate``, a block-sensitive
     surrogate (``source="analytic"``) that keeps the tuner's argmin
-    meaningful and deterministic without a device.
+    deterministic without a device.  It is a test fixture, never a
+    stand-in for a measurement on the chip: a timing run that fails
+    there raises instead of being scored as if it had worked.
 
 The surrogate models what block sizes actually change on a weight-
 streaming dataflow kernel: every token-block restreams the stage's
@@ -251,11 +253,12 @@ def measure_candidate(cfg: ModelConfig, plan: StreamPlan, kind: str,
                       ) -> Tuple[float, str]:
     """Latency for one lint-legal candidate: ``(seconds, source)``.
 
-    Interpret mode (no TPU) falls back to the analytic surrogate unless
+    Interpret mode (no TPU) scores with the analytic surrogate unless
     ``force=True`` — forcing in interpret mode times the Python Pallas
     interpreter, which is only useful to exercise the wall-clock path in
-    tests.  A driver failure (OOM, unsupported shape) also degrades to
-    the surrogate rather than killing the tuning pass.
+    tests.  A failed timing run (a kernel the compiler refuses, VMEM or
+    HBM exhaustion) propagates: a candidate that cannot run is never
+    scored as if it could.
     """
     if interpret_default() and not force:
         return analytic_estimate(cfg, plan, stage, choice, platform), \
@@ -264,8 +267,4 @@ def measure_candidate(cfg: ModelConfig, plan: StreamPlan, kind: str,
     if fn is None:
         return analytic_estimate(cfg, plan, stage, choice, platform), \
             "analytic"
-    try:
-        return measure(fn, reps=reps, warmup=warmup), "measured"
-    except Exception:
-        return analytic_estimate(cfg, plan, stage, choice, platform), \
-            "analytic"
+    return measure(fn, reps=reps, warmup=warmup), "measured"

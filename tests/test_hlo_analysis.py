@@ -12,12 +12,7 @@ def _compile(fn, *args):
 
 
 def _xla_cost(compiled):
-    """``Compiled.cost_analysis()`` returns one dict per partition on older
-    jax (a list) and a plain dict on newer releases."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca
+    return compiled.cost_analysis()
 
 
 def test_plain_matmul_flops_match_xla():

@@ -85,7 +85,7 @@ def test_append_gather_round_trip(layout):
     """Tokens appended through the page indirection read back, in order,
     from ``gather_pages`` — for both cache layouts."""
     ps, n_pages, h, hd, b = 4, 3, 2, 8, 2
-    pool = jnp.zeros((1 + b * n_pages, ps, h, hd), jnp.float32)
+    pool = jnp.zeros((1 + b * n_pages, h, ps, hd), jnp.float32)
     table = jnp.asarray(
         np.arange(1, 1 + b * n_pages, dtype=np.int32).reshape(b, n_pages))
     nprng = np.random.default_rng(0)
@@ -114,7 +114,7 @@ def test_paged_append_overrun_routes_to_null(layout):
     survive."""
     ps, n_pages, h, hd, b = 4, 2, 2, 8, 1
     extent = ps * n_pages
-    pool = jnp.zeros((1 + n_pages, ps, h, hd), jnp.float32)
+    pool = jnp.zeros((1 + n_pages, h, ps, hd), jnp.float32)
     table = jnp.asarray([[1, 2]], np.int32)
     nprng = np.random.default_rng(11)
     toks = nprng.normal(size=(extent, b, h, hd)).astype(np.float32)
@@ -187,14 +187,13 @@ def test_paged_kernel_matches_eager_decode(hq, hkv, window):
     from repro.models.layers import decode_attention
 
     b, d, ps, n_pages = 3, 16, 8, 4
-    s = ps * n_pages
     nprng = np.random.default_rng(2)
     q = jnp.asarray(nprng.normal(size=(b, 1, hq, d)).astype(np.float32))
     k_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, ps, hkv, d)).astype(np.float32)
+        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32)
     ).astype(jnp.bfloat16)
     v_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, ps, hkv, d)).astype(np.float32)
+        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32)
     ).astype(jnp.bfloat16)
     lengths = np.array([5, 17, 32], np.int32)
     table = np.zeros((b, n_pages), np.int32)
@@ -207,8 +206,8 @@ def test_paged_kernel_matches_eager_decode(hq, hkv, window):
 
     out = paged_decode_attention(q, k_pool, v_pool, table, lengths,
                                  window=window)
-    kc = k_pool[table].reshape(b, s, hkv, d)
-    vc = v_pool[table].reshape(b, s, hkv, d)
+    kc = gather_pages(k_pool, table, layout="bshd")
+    vc = gather_pages(v_pool, table, layout="bshd")
     ref = decode_attention(q, kc, vc, lengths, window=window, layout="bshd")
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=1e-5)

@@ -227,7 +227,7 @@ def test_release_is_exact_once_and_idempotent():
 def test_paged_append_cow_preserves_shared_page():
     ps, h, hd = 4, 2, 8
     nprng = np.random.default_rng(6)
-    pool = jnp.asarray(nprng.normal(size=(4, ps, h, hd)).astype(np.float32))
+    pool = jnp.asarray(nprng.normal(size=(4, h, ps, hd)).astype(np.float32))
     shared = np.asarray(pool[1])
     # Slot 0 diverges at position 2 inside shared page 1 -> COW to page 3.
     table = jnp.asarray([[3, 2]], np.int32)       # already redirected
@@ -236,8 +236,8 @@ def test_paged_append_cow_preserves_shared_page():
                        layout="bshd", cow_src=jnp.asarray([1], np.int32),
                        cow_dst=jnp.asarray([3], np.int32))
     np.testing.assert_array_equal(np.asarray(out[1]), shared)   # intact
-    np.testing.assert_array_equal(np.asarray(out[3][:2]), shared[:2])
-    np.testing.assert_array_equal(np.asarray(out[3][2]), 9.0)
+    np.testing.assert_array_equal(np.asarray(out[3][:, :2]), shared[:, :2])
+    np.testing.assert_array_equal(np.asarray(out[3][:, 2]), 9.0)
     # NULL pair no-ops for idle slots.
     out2 = paged_append(pool, table, jnp.asarray([2], np.int32), new,
                         layout="bshd",
@@ -249,14 +249,15 @@ def test_paged_append_cow_preserves_shared_page():
 def test_place_chunk_pages_cow_preserves_shared_page():
     ps, h, hd = 4, 2, 8
     nprng = np.random.default_rng(7)
-    pool = jnp.asarray(nprng.normal(size=(4, ps, h, hd)).astype(np.float32))
+    pool = jnp.asarray(nprng.normal(size=(4, h, ps, hd)).astype(np.float32))
     shared = np.asarray(pool[2])
     chunk = jnp.asarray(nprng.normal(size=(1, ps, h, hd)).astype(np.float32))
     out = place_chunk_pages(pool, chunk, jnp.asarray([3], np.int32),
                             layout="bshd", cow_src=jnp.int32(2),
                             cow_dst=jnp.int32(3))
     np.testing.assert_array_equal(np.asarray(out[2]), shared)   # intact
-    np.testing.assert_array_equal(np.asarray(out[3]), np.asarray(chunk[0]))
+    np.testing.assert_array_equal(np.asarray(out[3]),
+                                  np.asarray(chunk[0]).transpose(1, 0, 2))
 
 
 def test_prefill_chunk_cow_partial_last_page(rng):

@@ -83,7 +83,7 @@ def test_quantize_kv_zero_scale_is_safe():
 
 def _quant_pool(kind, pages=5, ps=4, h=2, hd=8):
     dtype = kv_quant_dtype(kind)
-    pool = jnp.zeros((pages, ps, h, hd), dtype)
+    pool = jnp.zeros((pages, h, ps, hd), dtype)
     scale = jnp.zeros((pages, h), jnp.float32)
     return pool, scale
 
@@ -162,8 +162,8 @@ def test_cow_copies_scale_row_with_value_page():
                                    cow_src=jnp.int32(1), cow_dst=jnp.int32(3))
     np.testing.assert_allclose(np.asarray(scale2)[3], np.asarray(scale)[1])
     got, src = np.asarray(pool2)[3], np.asarray(pool)[1]
-    np.testing.assert_array_equal(got[0], src[0])
-    np.testing.assert_array_equal(got[2:], src[2:])
+    np.testing.assert_array_equal(got[:, 0], src[:, 0])
+    np.testing.assert_array_equal(got[:, 2:], src[:, 2:])
     # ...and the shared source page itself never mutated.
     np.testing.assert_array_equal(np.asarray(pool2)[1], src)
 

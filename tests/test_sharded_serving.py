@@ -134,10 +134,10 @@ def test_sharded_engine_matches_single_device(name):
     assert L.DISPATCH_RECORDS["single"] == 0
 
     # KV page pools carry a kv_heads-sharded NamedSharding (model axis on
-    # the Hkv dim of [G, P, page_size, Hkv, hd]); 4 shards of the pool.
+    # the Hkv dim of [G, P, Hkv, page_size, hd]); 4 shards of the pool.
     assert eng.kv.kv_shards == 4
     for s in _kv_pool_shardings(eng):
-        assert s.spec[3] == "model", s.spec
+        assert s.spec[2] == "model", s.spec
     assert eng.metrics["sharded"] == 1
 
     # Greedy tokens match the single-device engine exactly.

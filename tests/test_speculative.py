@@ -116,15 +116,15 @@ def test_paged_verify_kernel_matches_eager(hq, hkv, window):
     through the page-table indirection, mixed per-slot offsets."""
     from repro.kernels import paged_verify_attention
     from repro.models.layers import verify_attention
+    from repro.serving.kv_cache import gather_pages
 
     b, d, ps, n_pages, w = 3, 16, 8, 5, 4
-    s = ps * n_pages
     nprng = np.random.default_rng(4)
     q = jnp.asarray(nprng.normal(size=(b, w, hq, d)).astype(np.float32))
     k_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, ps, hkv, d)).astype(np.float32))
+        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32))
     v_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, ps, hkv, d)).astype(np.float32))
+        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32))
     q_off = np.array([5, 17, 33], np.int32)
     table = np.zeros((b, n_pages), np.int32)
     nxt = 1
@@ -136,8 +136,8 @@ def test_paged_verify_kernel_matches_eager(hq, hkv, window):
 
     out = paged_verify_attention(q, k_pool, v_pool, table, q_off,
                                  window=window)
-    kc = k_pool[table].reshape(b, s, hkv, d)
-    vc = v_pool[table].reshape(b, s, hkv, d)
+    kc = gather_pages(k_pool, table, layout="bshd")
+    vc = gather_pages(v_pool, table, layout="bshd")
     ref = verify_attention(q, kc, vc, q_off, window=window, layout="bshd")
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=1e-5)

@@ -256,6 +256,25 @@ def test_measure_candidate_interpret_falls_back_to_analytic():
         break
 
 
+def test_measure_candidate_failed_timing_raises(monkeypatch):
+    """A timing run that fails is an error, never an analytic score: on
+    the chip a kernel the compiler refuses must not look tuned."""
+    import importlib
+    measure_mod = importlib.import_module("repro.tuning.measure")
+    cfg = _cfg()
+    plan = _plan(cfg)
+    kind, stage, choice = next(c for c in plan.stage_choices()
+                               if c[2].fused)
+
+    def refused(fn, **kw):
+        raise RuntimeError("Mosaic refused the block shape")
+
+    monkeypatch.setattr(measure_mod, "measure", refused)
+    with pytest.raises(RuntimeError, match="refused"):
+        measure_candidate(cfg, plan, kind, stage, choice,
+                          platform=TPU_V5E, force=True)
+
+
 def test_measure_wall_clock_path():
     calls = []
 
