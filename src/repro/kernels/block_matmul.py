@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default, pick_block
+from .common import interpret_default, kernel_name, pick_block
 
 # Autotune candidate lattice (tuning/autotune.py) in KernelChoice
 # block names (block_t/block_n map onto this wrapper's block_m/
@@ -65,4 +65,5 @@ def block_matmul(x: jax.Array, w: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        **kernel_name("block_matmul"),
     )(x, w)

@@ -29,6 +29,13 @@ def interpret_default() -> bool:
     return not on_tpu()
 
 
+def kernel_name(name: str) -> dict:
+    """``pl.pallas_call`` keywords naming a kernel: the custom call's name
+    and its ``kernel_metadata`` (``{"kernel": name}``), which the compiled
+    program carries (``frontend_attributes={kernel_metadata=...}``)."""
+    return {"name": name, "metadata": {"kernel": name}}
+
+
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 

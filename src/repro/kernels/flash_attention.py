@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default, pick_block
+from .common import interpret_default, kernel_name, pick_block
 
 # Autotune candidate lattice (tuning/autotune.py): query/KV stream
 # tile grid for the measured-latency tuner; lint-pruned pre-compile.
@@ -311,6 +311,7 @@ def flash_attention_2d(q: jax.Array, k: jax.Array, v: jax.Array, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((h, sq, d), q.dtype),
             interpret=interpret,
+            **kernel_name("flash_attention"),
         )(meta, *operands)
 
     return pl.pallas_call(
@@ -331,4 +332,5 @@ def flash_attention_2d(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((1, bq, d), jnp.float32),
         ],
         interpret=interpret,
+        **kernel_name("flash_attention"),
     )(q, k, v)

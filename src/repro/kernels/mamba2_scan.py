@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default
+from .common import interpret_default, kernel_name
 
 # Autotune candidate lattice (tuning/autotune.py): SSD chunk lengths
 # (the sequential-scan granule; larger chunks amortize the state
@@ -122,6 +122,7 @@ def mamba2_ssd_pallas(x: jax.Array, dt: jax.Array, a_log: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        **kernel_name("mamba2_scan"),
     )(xk, dtk.reshape(bh, s, 1), ak, bk, ck, dk)
 
     y = y.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)

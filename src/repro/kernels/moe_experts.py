@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default, pick_block
+from .common import interpret_default, kernel_name, pick_block
 
 # Autotune candidate lattice (tuning/autotune.py): the expert grid is
 # fixed by the config, so only the token tile is searched.
@@ -79,4 +79,5 @@ def moe_experts_pallas(x: jax.Array, gates: jax.Array, wg: jax.Array,
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         interpret=interpret,
+        **kernel_name("moe_experts"),
     )(x, gates, wg, wu, wd)

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import LANE, interpret_default, round_up
+from .common import LANE, interpret_default, kernel_name, round_up
 
 # Autotune candidate lattice (tuning/autotune.py): KV page sizes the
 # tuner scores for the paged decode stream.  A page is one block's
@@ -213,6 +213,7 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, w * g, dp), q.dtype),
         interpret=interpret,
+        **kernel_name("paged_attention"),
     )(q_off.astype(jnp.int32), page_table.astype(jnp.int32), *operands)
     return out.reshape(b, hkv, w, g, dp).transpose(0, 2, 1, 3, 4) \
               .reshape(b, w, hq, dp)[..., :d]

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import interpret_default, pick_block
+from .common import interpret_default, kernel_name, pick_block
 
 # Autotune candidate lattice (tuning/autotune.py): block-target grids
 # the measured-latency tuner scores for this kernel family.  Points
@@ -87,4 +87,5 @@ def rmsnorm_matmul(x: jax.Array, scale: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((bt, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t, n), x.dtype),
         interpret=interpret,
+        **kernel_name("rmsnorm_matmul"),
     )(*operands)

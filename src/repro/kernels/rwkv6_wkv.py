@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default
+from .common import interpret_default, kernel_name
 
 # Autotune candidate lattice (tuning/autotune.py): WKV chunk lengths.
 # The N x N state outer products grow quadratically with the chunk,
@@ -95,6 +95,7 @@ def wkv6_pallas(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
+        **kernel_name("rwkv6_wkv"),
     )(flat(r), flat(k), flat(v), flat(w), uk)
     return (y.reshape(bsz, h, s, n).transpose(0, 2, 1, 3),
             state.reshape(bsz, h, n, n))

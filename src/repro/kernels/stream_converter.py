@@ -24,7 +24,7 @@ from jax.experimental import pallas as pl
 
 from ..core.converter import infer_converter
 from ..core.itensor import ITensorType
-from .common import interpret_default
+from .common import interpret_default, kernel_name
 
 
 def _copy_kernel(src_ref, dst_ref):
@@ -63,6 +63,7 @@ def convert_layout(data: jax.Array, src: ITensorType, dst: ITensorType, *,
             out_specs=pl.BlockSpec((1, eh, ew), lambda t: (t, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((n_tokens, eh, ew), data.dtype),
             interpret=interpret,
+            **kernel_name("stream_converter"),
         )(data)
 
     # Window buffer path: grid over the consumer stream; every tile read
@@ -92,6 +93,7 @@ def convert_layout(data: jax.Array, src: ITensorType, dst: ITensorType, *,
         out_specs=pl.BlockSpec((1, eh, ew), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tokens, eh, ew), data.dtype),
         interpret=interpret,
+        **kernel_name("stream_converter"),
     )(data)
 
 

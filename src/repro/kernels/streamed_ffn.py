@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default, pick_block
+from .common import interpret_default, kernel_name, pick_block
 
 # Autotune candidate lattice (tuning/autotune.py) shared by
 # streamed_ffn and streamed_mlp; lint-pruned before timing.
@@ -181,6 +181,7 @@ def streamed_ffn(x: jax.Array, wg: jax.Array, wu: jax.Array, wd: jax.Array,
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         interpret=interpret,
+        **kernel_name("streamed_ffn"),
     )(*operands)
 
 
@@ -289,4 +290,5 @@ def streamed_mlp(x: jax.Array, wu: jax.Array, wd: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         interpret=interpret,
+        **kernel_name("streamed_ffn"),
     )(*operands)

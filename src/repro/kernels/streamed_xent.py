@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default, pick_block
+from .common import interpret_default, kernel_name, pick_block
 
 # Autotune candidate lattice (tuning/autotune.py): vocab tiles are
 # the dominant stream (the [T, V] logits never materialize), token
@@ -105,6 +105,7 @@ def streamed_xent_parts(hidden: jax.Array, head: jax.Array,
             pltpu.VMEM((bt, 1), jnp.float32),
         ],
         interpret=interpret,
+        **kernel_name("streamed_xent"),
     )(hidden, head, labels)
     return lse, gold
 

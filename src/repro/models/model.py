@@ -91,6 +91,16 @@ def _cast_tree(cfg: ModelConfig, t: Tree) -> Tree:
                         t)
 
 
+def _lm_head(cfg: ModelConfig, params: Tree, h: jax.Array) -> jax.Array:
+    """f32 logits of ``h`` with the vocabulary's padding columns masked
+    out, so an argmax never picks one."""
+    with jax.named_scope("lm_head"):
+        logits = (h @ _c(cfg, params["lm_head"])).astype(jnp.float32)
+        vp = logits.shape[-1]
+        return jnp.where((jnp.arange(vp) >= cfg.vocab_size)[None, None],
+                         -1e30, logits)
+
+
 def _chunk_of(n: int, want: int) -> int:
     c = min(want, n)
     while n % c != 0:
@@ -486,10 +496,7 @@ def prefill(params: Tree, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
                                    collect=True, lplan=_lplan(plan, kind))
         caches_rest.append(jax.tree.map(lambda a: a[None], aux))
     x = L.apply_norm(cfg.norm, x, params["final_norm"])
-    logits = (x[:, -1:] @ _c(cfg, params["lm_head"])).astype(jnp.float32)
-    vp = logits.shape[-1]
-    logits = jnp.where((jnp.arange(vp) >= cfg.vocab_size)[None, None],
-                       -1e30, logits)
+    logits = _lm_head(cfg, params, x[:, -1:])
     return logits, {"blocks": caches_blocks, "rest": tuple(caches_rest)}
 
 
@@ -553,67 +560,79 @@ def _attn_block_chunk(cfg: ModelConfig, p: Tree, x: jax.Array, cache: Tree,
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     layout = cfg.kv_cache_layout
     ap = p["attn"]
-    q, k, v = _project_qkv(cfg, ap, x, p["ln1"], lplan)
-    q = q.reshape(b, c, hq, hd)
-    k = k.reshape(b, c, hkv, hd)
-    v = v.reshape(b, c, hkv, hd)
-    q, k = _qk_normed(cfg, ap, q, k)
-    positions = offset + jnp.arange(c)[None]               # [1, C]
-    q = L.apply_positional(cfg.rope, q, positions, cfg.rope_theta)
-    k = L.apply_positional(cfg.rope, k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _project_qkv(cfg, ap, x, p["ln1"], lplan)
+        q = q.reshape(b, c, hq, hd)
+        k = k.reshape(b, c, hkv, hd)
+        v = v.reshape(b, c, hkv, hd)
+        q, k = _qk_normed(cfg, ap, q, k)
+        positions = offset + jnp.arange(c)[None]           # [1, C]
+        q = L.apply_positional(cfg.rope, q, positions, cfg.rope_theta)
+        k = L.apply_positional(cfg.rope, k, positions, cfg.rope_theta)
     k_new = k.transpose(0, 2, 1, 3) if layout == "bhsd" else k
     v_new = v.transpose(0, 2, 1, 3) if layout == "bhsd" else v
     quant = "k_scale" in cache
-    if quant:
-        kc, ks = place_chunk_pages_q(cache["k"], cache["k_scale"], k_new,
-                                     chunk_pages, layout=layout,
-                                     cow_src=cow_src, cow_dst=cow_dst)
-        vc, vs = place_chunk_pages_q(cache["v"], cache["v_scale"], v_new,
-                                     chunk_pages, layout=layout,
-                                     cow_src=cow_src, cow_dst=cow_dst)
-    else:
-        kc = place_chunk_pages(cache["k"], k_new, chunk_pages, layout=layout,
-                               cow_src=cow_src, cow_dst=cow_dst)
-        vc = place_chunk_pages(cache["v"], v_new, chunk_pages, layout=layout,
-                               cow_src=cow_src, cow_dst=cow_dst)
-    # Bound KV traffic by the live prefix: the gather touches O(prefix)
-    # distinct pages instead of the slot's full table extent (masking at
-    # kv_len already discards the dead rows' scores).
-    row_live = live_page_table(table_row, kv_len, cache["k"].shape[2])
-    choice = lplan.attention if lplan is not None else None
-    fused = choice is not None and choice.fused
-    if quant and not fused:
-        # Eager reference: dense dequantized K/V through the same
-        # streaming-attention path the f32 cache takes.
-        kseq = gather_pages_dequant(kc, ks, row_live[None], layout=layout)
-        vseq = gather_pages_dequant(vc, vs, row_live[None], layout=layout)
-    else:
-        kseq = gather_pages(kc, row_live[None], layout=layout)
-        vseq = gather_pages(vc, row_live[None], layout=layout)
-    if layout == "bhsd":
-        kseq = kseq.transpose(0, 2, 1, 3)
-        vseq = vseq.transpose(0, 2, 1, 3)
-    if fused:
-        # The plan's flash kernel, offset twin: q_offset/kv_len ride in as
-        # scalar-prefetch operands so one compiled program covers every
-        # chunk index over any cache fill; the sharded dispatch (and the
-        # shard_map it builds) comes from the plan's sharding claim.
-        # Quantized: K/V stay codes and the per-page scale rows expand to
-        # per-position scale lanes the kernel consumes next to each tile.
-        scl = {}
+    with jax.named_scope("kv_append"):
         if quant:
-            ps_ = cache["k"].shape[2]
-            scl = {"k_scale": jnp.repeat(ks[row_live], ps_, axis=0)[None],
-                   "v_scale": jnp.repeat(vs[row_live], ps_, axis=0)[None]}
-        o = L.fused_attention_chunk(q, kseq, vseq, offset, kv_len,
-                                    causal=cfg.causal, window=window,
-                                    **scl, **choice.kw)
-    else:
-        o = L.streaming_attention(q, kseq, vseq, causal=cfg.causal,
-                                  q_offset=offset, window=window,
-                                  kv_len=kv_len)
-    x = x + o.reshape(b, c, hq * hd) @ ap["wo"]
-    x = x + _ffn_block(cfg, p["mlp"], x, p["ln2"], lplan)
+            kc, ks = place_chunk_pages_q(cache["k"], cache["k_scale"],
+                                         k_new, chunk_pages, layout=layout,
+                                         cow_src=cow_src, cow_dst=cow_dst)
+            vc, vs = place_chunk_pages_q(cache["v"], cache["v_scale"],
+                                         v_new, chunk_pages, layout=layout,
+                                         cow_src=cow_src, cow_dst=cow_dst)
+        else:
+            kc = place_chunk_pages(cache["k"], k_new, chunk_pages,
+                                   layout=layout, cow_src=cow_src,
+                                   cow_dst=cow_dst)
+            vc = place_chunk_pages(cache["v"], v_new, chunk_pages,
+                                   layout=layout, cow_src=cow_src,
+                                   cow_dst=cow_dst)
+    with jax.named_scope("attention"):
+        # Bound KV traffic by the live prefix: the gather touches
+        # O(prefix) distinct pages instead of the slot's full table extent
+        # (masking at kv_len already discards the dead rows' scores).
+        row_live = live_page_table(table_row, kv_len, cache["k"].shape[2])
+        choice = lplan.attention if lplan is not None else None
+        fused = choice is not None and choice.fused
+        if quant and not fused:
+            # Eager reference: dense dequantized K/V through the same
+            # streaming-attention path the f32 cache takes.
+            kseq = gather_pages_dequant(kc, ks, row_live[None],
+                                        layout=layout)
+            vseq = gather_pages_dequant(vc, vs, row_live[None],
+                                        layout=layout)
+        else:
+            kseq = gather_pages(kc, row_live[None], layout=layout)
+            vseq = gather_pages(vc, row_live[None], layout=layout)
+        if layout == "bhsd":
+            kseq = kseq.transpose(0, 2, 1, 3)
+            vseq = vseq.transpose(0, 2, 1, 3)
+        if fused:
+            # The plan's flash kernel, offset twin: q_offset/kv_len ride
+            # in as scalar-prefetch operands so one compiled program
+            # covers every chunk index over any cache fill; the sharded
+            # dispatch (and the shard_map it builds) comes from the plan's
+            # sharding claim.  Quantized: K/V stay codes and the per-page
+            # scale rows expand to per-position scale lanes the kernel
+            # consumes next to each tile.
+            scl = {}
+            if quant:
+                ps_ = cache["k"].shape[2]
+                scl = {"k_scale": jnp.repeat(ks[row_live], ps_,
+                                             axis=0)[None],
+                       "v_scale": jnp.repeat(vs[row_live], ps_,
+                                             axis=0)[None]}
+            o = L.fused_attention_chunk(q, kseq, vseq, offset, kv_len,
+                                        causal=cfg.causal, window=window,
+                                        **scl, **choice.kw)
+        else:
+            o = L.streaming_attention(q, kseq, vseq, causal=cfg.causal,
+                                      q_offset=offset, window=window,
+                                      kv_len=kv_len)
+    with jax.named_scope("out_proj"):
+        x = x + o.reshape(b, c, hq * hd) @ ap["wo"]
+    with jax.named_scope("ffn"):
+        x = x + _ffn_block(cfg, p["mlp"], x, p["ln2"], lplan)
     new_kv = {"k": kc, "v": vc}
     if quant:
         new_kv.update(k_scale=ks, v_scale=vs)
@@ -676,11 +695,14 @@ def prefill_chunk(params: Tree, cfg: ModelConfig, tokens: jax.Array,
     params = _cast_tree(cfg, params)
     b, c = tokens.shape
     offset = jnp.asarray(offset, jnp.int32)
-    x = _c(cfg, jnp.take(params["embed"], tokens, axis=0))
-    x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    if cfg.rope == "none" and "pos_embed" in params:
-        positions = jnp.broadcast_to(offset + jnp.arange(c)[None], (b, c))
-        x = x + jnp.take(_c(cfg, params["pos_embed"]), positions, axis=0)
+    with jax.named_scope("embed"):
+        x = _c(cfg, jnp.take(params["embed"], tokens, axis=0))
+        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        if cfg.rope == "none" and "pos_embed" in params:
+            positions = jnp.broadcast_to(offset + jnp.arange(c)[None],
+                                         (b, c))
+            x = x + jnp.take(_c(cfg, params["pos_embed"]), positions,
+                             axis=0)
     # Plan keyed on the chunk token count and the gathered cache extent —
     # both static, so the plan (like the program) is one per engine.
     kv_extent = int(table_row.shape[0]) * _cache_page_size(cache)
@@ -703,8 +725,12 @@ def prefill_chunk(params: Tree, cfg: ModelConfig, tokens: jax.Array,
         return x, tuple(new_caches)
 
     if groups > 0:
-        x, new_blocks = lax.scan(group_body, x,
-                                 (params["blocks"], cache["blocks"]))
+        # ``layers`` names the scan's own ops: each layer's weights and
+        # K/V pools sliced out of the stacked arrays, the pools written
+        # back.
+        with jax.named_scope("layers"):
+            x, new_blocks = lax.scan(group_body, x,
+                                     (params["blocks"], cache["blocks"]))
     else:
         new_blocks = ()
     new_rest = []
@@ -716,14 +742,13 @@ def prefill_chunk(params: Tree, cfg: ModelConfig, tokens: jax.Array,
                                    lplan=_lplan(plan, kind),
                                    cow_src=cow_src, cow_dst=cow_dst)
         new_rest.append(jax.tree.map(lambda a: a[None], nc))
-    x = L.apply_norm(cfg.norm, x, params["final_norm"])
-    h_last = lax.dynamic_slice_in_dim(x, jnp.asarray(last_idx, jnp.int32),
-                                      1, axis=1)            # [1, 1, D]
-    logits = (h_last @ _c(cfg, params["lm_head"])).astype(jnp.float32)
-    vp = logits.shape[-1]
-    logits = jnp.where((jnp.arange(vp) >= cfg.vocab_size)[None, None],
-                       -1e30, logits)
-    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("final_norm"):
+        x = L.apply_norm(cfg.norm, x, params["final_norm"])
+        h_last = lax.dynamic_slice_in_dim(
+            x, jnp.asarray(last_idx, jnp.int32), 1, axis=1)  # [1, 1, D]
+    logits = _lm_head(cfg, params, h_last)
+    with jax.named_scope("argmax"):
+        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return next_tokens, logits, {"blocks": new_blocks,
                                  "rest": tuple(new_rest)}
 
@@ -770,19 +795,20 @@ def _attn_block_decode(cfg: ModelConfig, p: Tree, x: jax.Array,
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     layout = cfg.kv_cache_layout
     ap = p["attn"]
-    q, k, v = _project_qkv(cfg, ap, x, p["ln1"], lplan)
-    q = q.reshape(b, 1, hq, hd)
-    k = k.reshape(b, 1, hkv, hd)
-    v = v.reshape(b, 1, hkv, hd)
-    q, k = _qk_normed(cfg, ap, q, k)
     pos = _decode_positions(cache_pos, b)[:, None]          # [B, 1]
-    if cfg.rope == "mrope":
-        pos3 = jnp.broadcast_to(pos[None], (3, b, 1))
-        q = L.apply_positional(cfg.rope, q, pos3, cfg.rope_theta)
-        k = L.apply_positional(cfg.rope, k, pos3, cfg.rope_theta)
-    else:
-        q = L.apply_positional(cfg.rope, q, pos, cfg.rope_theta)
-        k = L.apply_positional(cfg.rope, k, pos, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q, k, v = _project_qkv(cfg, ap, x, p["ln1"], lplan)
+        q = q.reshape(b, 1, hq, hd)
+        k = k.reshape(b, 1, hkv, hd)
+        v = v.reshape(b, 1, hkv, hd)
+        q, k = _qk_normed(cfg, ap, q, k)
+        if cfg.rope == "mrope":
+            pos3 = jnp.broadcast_to(pos[None], (3, b, 1))
+            q = L.apply_positional(cfg.rope, q, pos3, cfg.rope_theta)
+            k = L.apply_positional(cfg.rope, k, pos3, cfg.rope_theta)
+        else:
+            q = L.apply_positional(cfg.rope, q, pos, cfg.rope_theta)
+            k = L.apply_positional(cfg.rope, k, pos, cfg.rope_theta)
     k_new = k.transpose(0, 2, 1, 3) if layout == "bhsd" else k
     v_new = v.transpose(0, 2, 1, 3) if layout == "bhsd" else v
     if page_table is not None:
@@ -797,34 +823,40 @@ def _attn_block_decode(cfg: ModelConfig, p: Tree, x: jax.Array,
         pos_v = pos[:, 0]
         quant = "k_scale" in cache
         ks = vs = None
-        if quant:
-            kc, ks = paged_append_q(cache["k"], cache["k_scale"],
-                                    page_table, pos_v, k_new, layout=layout)
-            vc, vs = paged_append_q(cache["v"], cache["v_scale"],
-                                    page_table, pos_v, v_new, layout=layout)
-        else:
-            kc = paged_append(cache["k"], page_table, pos_v, k_new,
-                              layout=layout)
-            vc = paged_append(cache["v"], page_table, pos_v, v_new,
-                              layout=layout)
-        choice = lplan.decode_attn if lplan is not None else None
-        if choice is not None and choice.fused:
-            o = L.fused_paged_attention(q, kc, vc, page_table, lengths + 1,
-                                        window=window, k_scale=ks,
-                                        v_scale=vs, shard=choice.sharding)
-        else:
-            # Bound the gather by each slot's live prefix, mirroring the
-            # chunk path (the length mask already discards dead rows).
-            tbl_live = live_page_table(page_table, lengths + 1,
-                                       cache["k"].shape[2])
+        with jax.named_scope("kv_append"):
             if quant:
-                kd = gather_pages_dequant(kc, ks, tbl_live, layout=layout)
-                vd = gather_pages_dequant(vc, vs, tbl_live, layout=layout)
+                kc, ks = paged_append_q(cache["k"], cache["k_scale"],
+                                        page_table, pos_v, k_new,
+                                        layout=layout)
+                vc, vs = paged_append_q(cache["v"], cache["v_scale"],
+                                        page_table, pos_v, v_new,
+                                        layout=layout)
             else:
-                kd = gather_pages(kc, tbl_live, layout=layout)
-                vd = gather_pages(vc, tbl_live, layout=layout)
-            o = L.decode_attention(q, kd, vd, lengths + 1, window=window,
-                                   layout=layout)
+                kc = paged_append(cache["k"], page_table, pos_v, k_new,
+                                  layout=layout)
+                vc = paged_append(cache["v"], page_table, pos_v, v_new,
+                                  layout=layout)
+        choice = lplan.decode_attn if lplan is not None else None
+        with jax.named_scope("attention"):
+            if choice is not None and choice.fused:
+                o = L.fused_paged_attention(
+                    q, kc, vc, page_table, lengths + 1, window=window,
+                    k_scale=ks, v_scale=vs, shard=choice.sharding)
+            else:
+                # Bound the gather by each slot's live prefix, mirroring
+                # the chunk path (the length mask discards dead rows).
+                tbl_live = live_page_table(page_table, lengths + 1,
+                                           cache["k"].shape[2])
+                if quant:
+                    kd = gather_pages_dequant(kc, ks, tbl_live,
+                                              layout=layout)
+                    vd = gather_pages_dequant(vc, vs, tbl_live,
+                                              layout=layout)
+                else:
+                    kd = gather_pages(kc, tbl_live, layout=layout)
+                    vd = gather_pages(vc, tbl_live, layout=layout)
+                o = L.decode_attention(q, kd, vd, lengths + 1,
+                                       window=window, layout=layout)
     else:
         from .params import kv_seq_axis
         ax = kv_seq_axis(layout)
@@ -838,12 +870,16 @@ def _attn_block_decode(cfg: ModelConfig, p: Tree, x: jax.Array,
             return lax.dynamic_update_slice_in_dim(
                 c, new.astype(c.dtype), p_, axis=ax)
 
-        kc = jax.vmap(upd)(cache["k"], k_new, pos_w)
-        vc = jax.vmap(upd)(cache["v"], v_new, pos_w)
-        o = L.decode_attention(q, kc, vc, lengths + 1, window=window,
-                               layout=layout)
-    x = x + o.reshape(b, 1, hq * hd) @ ap["wo"]
-    x = x + _ffn_block(cfg, p["mlp"], x, p["ln2"], lplan)
+        with jax.named_scope("kv_append"):
+            kc = jax.vmap(upd)(cache["k"], k_new, pos_w)
+            vc = jax.vmap(upd)(cache["v"], v_new, pos_w)
+        with jax.named_scope("attention"):
+            o = L.decode_attention(q, kc, vc, lengths + 1, window=window,
+                                   layout=layout)
+    with jax.named_scope("out_proj"):
+        x = x + o.reshape(b, 1, hq * hd) @ ap["wo"]
+    with jax.named_scope("ffn"):
+        x = x + _ffn_block(cfg, p["mlp"], x, p["ln2"], lplan)
     new_kv = {"k": kc, "v": vc}
     if page_table is not None and "k_scale" in cache:
         new_kv.update(k_scale=ks, v_scale=vs)
@@ -955,11 +991,12 @@ def decode_step(params: Tree, cfg: ModelConfig, tokens: jax.Array,
     params = _cast_tree(cfg, params)
     b = tokens.shape[0]
     pos_v = _decode_positions(cache_pos, b)
-    x = _c(cfg, jnp.take(params["embed"], tokens, axis=0))
-    x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    if cfg.rope == "none" and "pos_embed" in params:
-        x = x + jnp.take(_c(cfg, params["pos_embed"]), pos_v,
-                         axis=0)[:, None]
+    with jax.named_scope("embed"):
+        x = _c(cfg, jnp.take(params["embed"], tokens, axis=0))
+        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        if cfg.rope == "none" and "pos_embed" in params:
+            x = x + jnp.take(_c(cfg, params["pos_embed"]), pos_v,
+                             axis=0)[:, None]
     plan = resolve_plan(cfg, b,
                         kv_len=_cache_kv_len(cfg, cache, page_table),
                         plan=plan)
@@ -980,8 +1017,12 @@ def decode_step(params: Tree, cfg: ModelConfig, tokens: jax.Array,
         return x, tuple(new_caches)
 
     if groups > 0:
-        x, new_blocks = lax.scan(group_body, x,
-                                 (params["blocks"], cache["blocks"]))
+        # ``layers`` names the scan's own ops: each layer's weights and
+        # K/V pools sliced out of the stacked arrays, the pools written
+        # back.
+        with jax.named_scope("layers"):
+            x, new_blocks = lax.scan(group_body, x,
+                                     (params["blocks"], cache["blocks"]))
     else:
         new_blocks = ()
     new_rest = []
@@ -993,12 +1034,11 @@ def decode_step(params: Tree, cfg: ModelConfig, tokens: jax.Array,
                                     lplan=_lplan(plan, kind),
                                     page_table=page_table)
         new_rest.append(jax.tree.map(lambda a: a[None], nc))
-    x = L.apply_norm(cfg.norm, x, params["final_norm"])
-    logits = (x @ _c(cfg, params["lm_head"])).astype(jnp.float32)
-    vp = logits.shape[-1]
-    logits = jnp.where((jnp.arange(vp) >= cfg.vocab_size)[None, None],
-                       -1e30, logits)
-    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("final_norm"):
+        x = L.apply_norm(cfg.norm, x, params["final_norm"])
+    logits = _lm_head(cfg, params, x)
+    with jax.named_scope("argmax"):
+        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     new_cache = {"blocks": new_blocks, "rest": tuple(new_rest)}
     return next_tokens, logits, new_cache
 
@@ -1179,10 +1219,7 @@ def verify_step(params: Tree, cfg: ModelConfig, tokens: jax.Array,
                                     page_table=page_table)
         new_rest.append(jax.tree.map(lambda a: a[None], nc))
     x = L.apply_norm(cfg.norm, x, params["final_norm"])
-    logits = (x @ _c(cfg, params["lm_head"])).astype(jnp.float32)
-    vp = logits.shape[-1]
-    logits = jnp.where((jnp.arange(vp) >= cfg.vocab_size)[None, None],
-                       -1e30, logits)
+    logits = _lm_head(cfg, params, x)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     new_cache = {"blocks": new_blocks, "rest": tuple(new_rest)}
     return greedy, logits, new_cache

@@ -17,6 +17,7 @@ three artifacts; DESIGN.md §17 documents the taxonomy and formats.
 from .events import (
     Clock,
     Event,
+    HostSpan,
     ManualClock,
     NULL_RECORDER,
     NullRecorder,
@@ -29,6 +30,19 @@ from .events import (  # noqa: F401  (event-name vocabulary)
     DISPATCH_PREFILL,
     DISPATCH_PREFILL_CHUNK,
     DISPATCH_VERIFY,
+    DEVICE_WAITS,
+    ENGINE_ADMIT,
+    ENGINE_CHUNK_STAGE,
+    ENGINE_DECODE_ENQUEUE,
+    ENGINE_DECODE_PREP,
+    ENGINE_GENERATE,
+    ENGINE_HARVEST,
+    ENGINE_PREFILL_ENQUEUE,
+    ENGINE_PREFIX_INSERT,
+    ENGINE_RETIRE,
+    ENGINE_VERIFY_ENQUEUE,
+    ENQUEUES,
+    HOST_PHASES,
     PAGE_ALLOC,
     PAGE_COW,
     PAGE_EVICT,
@@ -54,6 +68,10 @@ from .events import (  # noqa: F401  (event-name vocabulary)
     TRACK_TUNE,
     TUNE_MEASURE,
     TUNE_PRUNE,
+    WAIT_DECODE,
+    WAIT_FIRST_TOKEN,
+    WAIT_PREFILL,
+    WAIT_VERIFY,
 )
 from .export import (
     chrome_trace,
@@ -72,8 +90,8 @@ from .metrics import (
 )
 
 __all__ = [
-    "Clock", "Event", "ManualClock", "NullRecorder", "NULL_RECORDER",
-    "Recorder", "resolve_recorder", "slot_track",
+    "Clock", "Event", "HostSpan", "ManualClock", "NullRecorder",
+    "NULL_RECORDER", "Recorder", "resolve_recorder", "slot_track",
     "Counter", "Gauge", "Histogram", "Info", "MetricsView", "Registry",
     "log_buckets",
     "chrome_trace", "events_jsonl", "prometheus_text",

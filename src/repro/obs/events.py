@@ -45,6 +45,11 @@ the full table):
     ``prefix.evict``
   * tuner — ``tune.measure`` / ``tune.prune``
   * scheduler — ``sched.budget`` / ``sched.admit_wave``
+  * engine host phases — ``engine.*`` host work and ``wait.*`` device
+    read-backs: the leaf spans that tile one ``generate()`` pass, put on
+    the profiler's timeline by ``HostSpan`` (and on the ``engine``
+    track when recording), beside their ``dispatch.*`` parents and a
+    ``req.first_token`` mark
 """
 
 from __future__ import annotations
@@ -52,6 +57,8 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 Clock = Callable[[], float]
 
@@ -69,6 +76,34 @@ DISPATCH_PREFILL = "dispatch.prefill"
 DISPATCH_PREFILL_CHUNK = "dispatch.prefill_chunk"
 DISPATCH_DECODE = "dispatch.decode"
 DISPATCH_VERIFY = "dispatch.verify"
+
+# Engine host phases: the leaf spans that tile one ``generate()`` call on
+# the profiler's timeline, under an ``engine.generate`` parent (the loop's
+# own bookkeeping between phases reads as that).  ``DISPATCH_PREFILL_CHUNK``
+# doubles as the chunk program's enqueue (the interval ``chunk_latency_s``
+# times); the decode, verify and whole-prompt dispatch spans above are
+# parents of an enqueue and a device wait.
+ENGINE_GENERATE = "engine.generate"              # parent: one generate()
+ENGINE_ADMIT = "engine.admit"                    # admission pass
+ENGINE_CHUNK_STAGE = "engine.chunk_stage"        # catch-up, pages, staging
+ENGINE_PREFIX_INSERT = "engine.prefix_insert"    # prompt pages into tree
+ENGINE_DECODE_PREP = "engine.decode_prep"        # drafts, COW, pages, positions
+ENGINE_DECODE_ENQUEUE = "engine.decode_enqueue"
+ENGINE_VERIFY_ENQUEUE = "engine.verify_enqueue"
+ENGINE_PREFILL_ENQUEUE = "engine.prefill_enqueue"
+ENGINE_HARVEST = "engine.harvest"                # tokens, stamps, rollbacks
+ENGINE_RETIRE = "engine.retire"                  # finished slots' pages freed
+# Device waits: host reads that block until the device program finishes.
+WAIT_FIRST_TOKEN = "wait.first_token"            # the final chunk's token
+WAIT_DECODE = "wait.decode"
+WAIT_VERIFY = "wait.verify"
+WAIT_PREFILL = "wait.prefill"
+
+ENQUEUES = (DISPATCH_PREFILL_CHUNK, ENGINE_DECODE_ENQUEUE,
+            ENGINE_VERIFY_ENQUEUE, ENGINE_PREFILL_ENQUEUE)
+HOST_PHASES = (ENGINE_ADMIT, ENGINE_CHUNK_STAGE, ENGINE_PREFIX_INSERT,
+               ENGINE_DECODE_PREP, ENGINE_HARVEST, ENGINE_RETIRE) + ENQUEUES
+DEVICE_WAITS = (WAIT_FIRST_TOKEN, WAIT_DECODE, WAIT_VERIFY, WAIT_PREFILL)
 
 # Compile probes: emitted while jit TRACES the dispatch body, so the
 # event count equals the engine's programs-built counters.
@@ -275,6 +310,34 @@ class NullRecorder:
 
 
 NULL_RECORDER = NullRecorder()
+
+
+class HostSpan:
+    """One engine host phase, emitted once to both sinks: always as a
+    ``jax.profiler.TraceAnnotation`` (about 1 us when no profile is being
+    taken), so the phase sits on the device trace's clock, and as a span
+    on ``rec``'s engine track (with ``args``) when the recorder is
+    enabled.  ``t0``/``dur`` are read on ``clock`` and stay readable after
+    the block, for the engine's own histograms."""
+
+    __slots__ = ("_rec", "_clock", "_ann", "_args", "name", "t0", "dur")
+
+    def __init__(self, rec, clock: Clock, name: str, **args: Any):
+        self._rec, self._clock, self.name, self._args = rec, clock, name, args
+        self._ann = TraceAnnotation(name)
+        self.t0 = self.dur = 0.0
+
+    def __enter__(self) -> "HostSpan":
+        self._ann.__enter__()
+        self.t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dur = self._clock() - self.t0
+        self._ann.__exit__(*exc)
+        if self._rec.enabled:
+            self._rec.complete(self.name, self.t0, self.dur,
+                               track=TRACK_ENGINE, **self._args)
 
 
 def resolve_recorder(spec, *, clock: Optional[Clock] = None):

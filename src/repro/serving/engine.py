@@ -131,11 +131,17 @@ from ..models import (decode_step, init_cache, prefill, resolve_plan,
 from ..models import prefill_chunk as _model_prefill_chunk
 from ..models.params import cache_leaf_kind, cache_leaf_name
 from ..obs import (DISPATCH_DECODE, DISPATCH_PREFILL,
-                   DISPATCH_PREFILL_CHUNK, DISPATCH_VERIFY, MetricsView,
-                   Registry, REQ_ADMITTED, REQ_FINISHED, REQ_FIRST_TOKEN,
-                   REQ_PREFILL_CHUNK, REQ_QUEUED, REQ_REJECTED,
-                   SCHED_BUDGET, TRACE_DECODE, TRACE_PREFILL, TRACE_VERIFY,
-                   TRACK_ENGINE, TRACK_SCHED, resolve_recorder, slot_track)
+                   DISPATCH_PREFILL_CHUNK, DISPATCH_VERIFY, ENGINE_ADMIT,
+                   ENGINE_CHUNK_STAGE, ENGINE_DECODE_ENQUEUE,
+                   ENGINE_DECODE_PREP, ENGINE_GENERATE, ENGINE_HARVEST,
+                   ENGINE_PREFILL_ENQUEUE, ENGINE_PREFIX_INSERT,
+                   ENGINE_RETIRE, ENGINE_VERIFY_ENQUEUE, HostSpan,
+                   MetricsView, NULL_RECORDER, Registry, REQ_ADMITTED,
+                   REQ_FINISHED, REQ_FIRST_TOKEN, REQ_PREFILL_CHUNK,
+                   REQ_QUEUED, REQ_REJECTED, SCHED_BUDGET, TRACE_DECODE,
+                   TRACE_PREFILL, TRACE_VERIFY, TRACK_ENGINE, TRACK_SCHED,
+                   WAIT_DECODE, WAIT_FIRST_TOKEN, WAIT_PREFILL, WAIT_VERIFY,
+                   resolve_recorder, slot_track)
 from .kv_cache import (NULL_PAGE, PagedKVCache, cdiv, place_prefill,
                        stage_chunk)
 from .prefix_cache import PrefixCache
@@ -448,12 +454,15 @@ class ServingEngine:
                             return leaf
                         return leaf.at[:, cow_dst].set(leaf[:, cow_src])
 
-                    cache = jax.tree_util.tree_map_with_path(cow, cache)
+                    with jax.named_scope("cow"):
+                        cache = jax.tree_util.tree_map_with_path(cow, cache)
 
                 def tick(carry, _):
                     tok, cache, pos, lengths = carry
-                    nt, _lg, cache = decode_step(p, cfg, tok, cache, pos,
-                                                 lengths, page_table=table)
+                    with jax.named_scope("tick"):
+                        nt, _lg, cache = decode_step(
+                            p, cfg, tok, cache, pos, lengths,
+                            page_table=table)
                     return (nt, cache, pos + 1, lengths + 1), nt[:, 0]
 
                 carry, toks = lax.scan(tick, (tok, cache, pos, lengths),
@@ -477,8 +486,9 @@ class ServingEngine:
 
                 def tick(carry, _):
                     tok, cache, pos, lengths = carry
-                    nt, _lg, cache = decode_step(p, cfg, tok, cache, pos,
-                                                 lengths)
+                    with jax.named_scope("tick"):
+                        nt, _lg, cache = decode_step(p, cfg, tok, cache, pos,
+                                                     lengths)
                     return (nt, cache, pos + 1, lengths + 1), nt[:, 0]
 
                 carry, toks = lax.scan(tick, (tok, cache, pos, lengths),
@@ -508,7 +518,8 @@ class ServingEngine:
                             return leaf
                         return leaf.at[:, cow_dst].set(leaf[:, cow_src])
 
-                    cache = jax.tree_util.tree_map_with_path(cow, cache)
+                    with jax.named_scope("cow"):
+                        cache = jax.tree_util.tree_map_with_path(cow, cache)
                 greedy, _lg, cache = verify_step(p, cfg, toks, cache, pos,
                                                  lengths, page_table=table)
                 return greedy, cache
@@ -660,7 +671,9 @@ class ServingEngine:
         reg.histogram("ttft_s", "time to first token")
         reg.histogram("tpot_s", "time per output token after the first")
         reg.histogram("queue_wait_s", "submit-to-admission wait")
-        reg.histogram("chunk_latency_s", "prefill-chunk dispatch wall")
+        reg.histogram("chunk_latency_s",
+                      "prefill-chunk enqueue wall (no read-back: the chunk "
+                      "program's device time is in the profiler trace)")
         reg.histogram("prefill_dispatch_s",
                       "whole-prompt prefill dispatch wall")
         reg.histogram("decode_dispatch_s", "decode-block dispatch wall")
@@ -720,10 +733,20 @@ class ServingEngine:
             stack.enter_context(self._use_tuner(self.tuner))
         return stack
 
+    def _span(self, name: str, **args) -> HostSpan:
+        """A host phase of the engine: on the profiler's timeline always,
+        on the recorder's engine track when telemetry is on."""
+        return HostSpan(self.obs, self.clock, name, **args)
+
     # -------------------------------------------------------------- API
     def generate(self, prompts: List[np.ndarray],
                  max_new_tokens: int = 16) -> List[Request]:
         """Serve a list of prompts (any mix of lengths) to completion."""
+        with self._span(ENGINE_GENERATE):
+            return self._serve(prompts, max_new_tokens)
+
+    def _serve(self, prompts: List[np.ndarray],
+               max_new_tokens: int) -> List[Request]:
         self.registry.mark()        # open the ``last_generate`` window
         reqs = [Request(rid=i, prompt=np.asarray(p),
                         max_new_tokens=max_new_tokens,
@@ -742,7 +765,9 @@ class ServingEngine:
         tok = np.zeros((self.slots, 1), np.int32)
 
         while pending or any(r is not None for r in active):
-            self._admit_pending(pending, active, decoding, pos, tok)
+            if pending:
+                with self._span(ENGINE_ADMIT):
+                    self._admit_pending(pending, active, decoding, pos, tok)
             if not any(r is not None for r in active):
                 break                               # nothing admitted ran
             progressed = False
@@ -928,9 +953,12 @@ class ServingEngine:
     def _stamp_first_token(self, r: Request, slot: int) -> None:
         """First output token exists: stamp it, observe TTFT, emit the
         lifecycle instant.  Call sites guard on ``first_token_at <= 0``
-        where a slot can reach this more than once."""
-        r.first_token_at = self.clock()
-        self.registry["ttft_s"].observe(r.ttft_s)
+        where a slot can reach this more than once.  The profiler gets a
+        ``req.first_token`` mark on both first-token paths; the recorder
+        holds it as the instant."""
+        with HostSpan(NULL_RECORDER, self.clock, REQ_FIRST_TOKEN):
+            r.first_token_at = self.clock()
+            self.registry["ttft_s"].observe(r.ttft_s)
         if self.obs.enabled:
             self.obs.instant(REQ_FIRST_TOKEN, track=slot_track(slot),
                              ts=r.first_token_at, rid=r.rid,
@@ -975,30 +1003,30 @@ class ServingEngine:
             raise ValueError(
                 f"prompt length {plen} exceeds max_len {self.max_len}")
         batch = {"tokens": jnp.asarray(r.prompt)[None]}
-        t0 = self.clock()
-        with self._mesh_ctx():
-            if self.kv is not None:
-                pages = jnp.asarray(self.kv.ensure(slot, plen))
-                next_tok, cache = self._prefill(
-                    self.params, batch, self._slot_cache, jnp.int32(slot),
-                    pages)
-            else:
-                next_tok, cache = self._prefill(
-                    self.params, batch, self._slot_cache, jnp.int32(slot))
-        # Reassign immediately after every donating dispatch: the donated
-        # input buffer is deleted on accelerator backends, and a mid-wave
-        # exception must not leave the engine holding a dead reference.
-        self._slot_cache = cache
-        t = int(np.asarray(next_tok)[0, 0])
-        dt = self.clock() - t0       # host-visible dispatch wall (the
-        #                              np.asarray read-back synchronizes)
-        self.registry["prefill_dispatch_s"].observe(dt)
+        # Host-visible dispatch wall: the read-back synchronizes.
+        with self._span(DISPATCH_PREFILL, slot=slot, rid=r.rid,
+                        tokens=plen) as sp:
+            with self._span(ENGINE_PREFILL_ENQUEUE), self._mesh_ctx():
+                if self.kv is not None:
+                    pages = jnp.asarray(self.kv.ensure(slot, plen))
+                    next_tok, cache = self._prefill(
+                        self.params, batch, self._slot_cache,
+                        jnp.int32(slot), pages)
+                else:
+                    next_tok, cache = self._prefill(
+                        self.params, batch, self._slot_cache,
+                        jnp.int32(slot))
+                # Reassign immediately after every donating dispatch: the
+                # donated input buffer is deleted on accelerator backends,
+                # and a mid-wave exception must not leave the engine
+                # holding a dead reference.
+                self._slot_cache = cache
+            with self._span(WAIT_PREFILL):
+                t = int(np.asarray(next_tok)[0, 0])
+        self.registry["prefill_dispatch_s"].observe(sp.dur)
         if self.obs.enabled:
-            self.obs.complete(DISPATCH_PREFILL, t0, dt,
-                              track=TRACK_ENGINE, slot=slot, rid=r.rid,
-                              tokens=plen)
-            self.obs.complete("prefill", t0, dt, track=slot_track(slot),
-                              rid=r.rid, tokens=plen)
+            self.obs.complete("prefill", sp.t0, sp.dur,
+                              track=slot_track(slot), rid=r.rid, tokens=plen)
         r.out_tokens.append(t)
         self._stamp_first_token(r, slot)
         r.prefill_pos = plen
@@ -1017,33 +1045,39 @@ class ServingEngine:
         assert self.kv is not None and self._prefill_chunk is not None
         c = self.chunk
         plen = int(r.prompt.shape[0])
-        off = r.prefill_pos
-        if self.prefix is not None and self._cow[slot] is None:
-            # Catch-up walk: pages for our NEXT chunks may have appeared
-            # since admission (a same-wave request computing the shared
-            # prefix inserts as it completes) — claim them and skip ahead.
-            off, caught = self.prefix.extend_claim(slot, r.prompt, off)
-            if caught:
-                r.prefill_pos = off
-                self.registry["prefix_hit_pages"].inc(caught)
-        # Pages for the chunk's span (page-aligned by construction); the
-        # portion of a final chunk past max_len maps to the NULL page.
-        # An allocator failure here (pool pressure with every cached page
-        # still referenced) fails THIS request without stranding the
-        # stream — its already-placed pages return exactly once.
-        try:
-            self.kv.ensure(slot, min(off + c, self.max_len))
-        except RuntimeError as e:
-            r.failed = True
-            r.error = str(e)
-            self.registry["rejected"].inc()
-            self._retire(slot, r, active, decoding, pos, tok)
-            return
-        row = self.kv.table_row(slot)
-        toks, cpages, last = stage_chunk(r.prompt, off, c, row,
-                                         self.kv.page_size)
-        t0 = self.clock()
-        with self._mesh_ctx():
+        with self._span(ENGINE_CHUNK_STAGE):
+            off = r.prefill_pos
+            if self.prefix is not None and self._cow[slot] is None:
+                # Catch-up walk: pages for our NEXT chunks may have
+                # appeared since admission (a same-wave request computing
+                # the shared prefix inserts as it completes) — claim them
+                # and skip ahead.
+                off, caught = self.prefix.extend_claim(slot, r.prompt, off)
+                if caught:
+                    r.prefill_pos = off
+                    self.registry["prefix_hit_pages"].inc(caught)
+            # Pages for the chunk's span (page-aligned by construction);
+            # the portion of a final chunk past max_len maps to the NULL
+            # page.  An allocator failure here (pool pressure with every
+            # cached page still referenced) fails THIS request without
+            # stranding the stream — its already-placed pages return
+            # exactly once.
+            try:
+                self.kv.ensure(slot, min(off + c, self.max_len))
+            except RuntimeError as e:
+                r.failed = True
+                r.error = str(e)
+                self.registry["rejected"].inc()
+                self._retire(slot, r, active, decoding, pos, tok)
+                return
+            row = self.kv.table_row(slot)
+            toks, cpages, last = stage_chunk(r.prompt, off, c, row,
+                                             self.kv.page_size)
+        ci = off // c
+        # The span times the enqueue only: the chunk program's device time
+        # is in the profiler's trace (module ``jit__chunk_fwd``).
+        with self._span(DISPATCH_PREFILL_CHUNK, slot=slot, rid=r.rid,
+                        chunk=ci, off=off) as sp, self._mesh_ctx():
             # The COW operands ride as NULL here: the engine's matching
             # policies never hand a chunk a shared write target (default
             # mode restarts on fresh pages; bootstrap full hits COW on
@@ -1055,15 +1089,10 @@ class ServingEngine:
                 jnp.asarray(row), jnp.asarray(cpages), jnp.int32(off),
                 jnp.int32(last), jnp.int32(NULL_PAGE),
                 jnp.int32(NULL_PAGE))
-        self._slot_cache = cache
-        dt = self.clock() - t0
-        self.registry["chunk_latency_s"].observe(dt)
+            self._slot_cache = cache
+        self.registry["chunk_latency_s"].observe(sp.dur)
         if self.obs.enabled:
-            ci = off // c
-            self.obs.complete(DISPATCH_PREFILL_CHUNK, t0, dt,
-                              track=TRACK_ENGINE, slot=slot, rid=r.rid,
-                              chunk=ci, off=off)
-            self.obs.complete("prefill_chunk", t0, dt,
+            self.obs.complete("prefill_chunk", sp.t0, sp.dur,
                               track=slot_track(slot), rid=r.rid, chunk=ci)
             self.obs.instant(REQ_PREFILL_CHUNK, track=slot_track(slot),
                              rid=r.rid, chunk=ci, off=off)
@@ -1074,18 +1103,21 @@ class ServingEngine:
         if self.prefix is not None:
             # Prefill done: the full prompt pages are final — index them
             # so concurrent and future requests share them.
-            self.prefix.insert(slot, r.prompt)
-        t = int(np.asarray(next_tok)[0, 0])
-        r.out_tokens.append(t)
-        self._stamp_first_token(r, slot)
-        pos[slot] = plen
-        tok[slot, 0] = t
-        decoding[slot] = True
-        self.registry["prefills"].inc()
-        self.registry["generated"].inc()
-        if (len(r.out_tokens) >= r.max_new_tokens
-                or pos[slot] >= self.max_len):
-            self._retire(slot, r, active, decoding, pos, tok)
+            with self._span(ENGINE_PREFIX_INSERT):
+                self.prefix.insert(slot, r.prompt)
+        with self._span(WAIT_FIRST_TOKEN):
+            t = int(np.asarray(next_tok)[0, 0])
+        with self._span(ENGINE_HARVEST):
+            r.out_tokens.append(t)
+            self._stamp_first_token(r, slot)
+            pos[slot] = plen
+            tok[slot, 0] = t
+            decoding[slot] = True
+            self.registry["prefills"].inc()
+            self.registry["generated"].inc()
+            done = (len(r.out_tokens) >= r.max_new_tokens
+                    or pos[slot] >= self.max_len)
+        self._retire_all([slot] if done else [], active, decoding, pos, tok)
 
     def _retire(self, slot: int, r: Request, active, decoding, pos,
                 tok) -> None:
@@ -1132,110 +1164,128 @@ class ServingEngine:
     def _decode_block(self, active, decoding, pos, tok) -> None:
         """One jitted dispatch: a block of scan ticks across all slots,
         each at its own position; harvest real tokens after."""
-        runnable = [s for s in range(self.slots)
-                    if active[s] is not None and decoding[s]]
-        block = self._decode_block_size(len(runnable))
-        self.registry["decode_block_last"].set(block)
-        if self.kv is not None:
-            # Pending copy-on-write pairs (prefix bootstrap: the next
-            # append lands inside a shared page) — resolve them to
-            # (src, dst) physical pages now so the dispatch copies the
-            # shared page onto the private one before the scan; the
-            # re-uploaded table already points at dst.
-            cow_src = np.full(self.slots, NULL_PAGE, np.int32)
-            cow_dst = np.full(self.slots, NULL_PAGE, np.int32)
-            for s in list(runnable):
-                r = active[s]
-                try:
-                    if self._cow[s] is not None:
-                        cow_src[s], cow_dst[s] = self.kv.cow_page(
-                            s, self._cow[s])
-                        self._cow[s] = None
-                        self.registry["cow_copies"].inc()
-                        # The slot's reference moved off the shared src:
-                        # refresh its eviction entry.
-                        self.prefix.page_released(int(cow_src[s]))
-                    # Allocate only what the request's remaining budget
-                    # can validly read back: scan ticks past the budget
-                    # write into unallocated positions, which route to
-                    # the NULL page, and their outputs are discarded
-                    # below.
-                    h = min(block, r.max_new_tokens - len(r.out_tokens))
-                    self.kv.ensure(s, min(int(pos[s]) + h, self.max_len))
-                except RuntimeError as e:
-                    # Pool pressure even after eviction — e.g. every
-                    # page referenced across slots while a bootstrap COW
-                    # needs its one transient extra page.  Fail THIS
-                    # request (pages returned exactly once via the
-                    # refcounted release) and keep the stream alive —
-                    # same contract as the chunk path.
-                    r.failed = True
-                    r.error = str(e)
-                    self.registry["rejected"].inc()
-                    self._retire(s, r, active, decoding, pos, tok)
-                    cow_src[s] = cow_dst[s] = NULL_PAGE
-            runnable = [s for s in runnable
+        with self._span(ENGINE_DECODE_PREP):
+            runnable = [s for s in range(self.slots)
                         if active[s] is not None and decoding[s]]
-            if not runnable:
-                return
-            # Idle slots AND slots parked mid-prefill ride along with
-            # their write position at the table extent: paged_append
-            # routes those writes to the NULL page, so a half-prefilled
-            # slot's pages survive the decode blocks between its chunks.
-            dpos = np.full(self.slots, self.kv.extent, np.int32)
-            dlen = np.zeros(self.slots, np.int32)
+            block = self._decode_block_size(len(runnable))
+            self.registry["decode_block_last"].set(block)
+            if self.kv is not None:
+                runnable, cow_src, cow_dst = self._provision(
+                    runnable, active, decoding, pos, tok,
+                    lambda s: min(block, active[s].max_new_tokens
+                                  - len(active[s].out_tokens)))
+                if not runnable:
+                    return
+                # Idle slots AND slots parked mid-prefill ride along with
+                # their write position at the table extent: paged_append
+                # routes those writes to the NULL page, so a half-prefilled
+                # slot's pages survive the decode blocks between its chunks.
+                dpos = np.full(self.slots, self.kv.extent, np.int32)
+                dlen = np.zeros(self.slots, np.int32)
+                for s in runnable:
+                    dpos[s] = pos[s]
+                    dlen[s] = pos[s]
+        with self._span(DISPATCH_DECODE, block=block,
+                        slots=len(runnable)) as sp:
+            with self._span(ENGINE_DECODE_ENQUEUE), self._mesh_ctx():
+                if self.kv is not None:
+                    next_tok, cache, toks = self._decode(
+                        self.params, jnp.asarray(tok), self._slot_cache,
+                        self.kv.page_table, jnp.asarray(dpos),
+                        jnp.asarray(dlen), jnp.asarray(cow_src),
+                        jnp.asarray(cow_dst), block)
+                else:
+                    next_tok, cache, toks = self._decode(
+                        self.params, jnp.asarray(tok), self._slot_cache,
+                        jnp.asarray(pos), jnp.asarray(pos), block)
+                self._slot_cache = cache
+            # The read-backs synchronize, so the parent span is the real
+            # device+host block wall.
+            with self._span(WAIT_DECODE):
+                toks_np = np.asarray(toks)               # [N, slots]
+                last_np = np.asarray(next_tok)           # [slots, 1]
+        with self._span(ENGINE_HARVEST):
+            self.registry["decode_dispatch_s"].observe(sp.dur)
+            if self.obs.enabled:
+                for s in runnable:
+                    self.obs.complete("decode", sp.t0, sp.dur,
+                                      track=slot_track(s),
+                                      rid=active[s].rid, block=block)
+            useful = 0
+            finished = []
             for s in runnable:
-                dpos[s] = pos[s]
-                dlen[s] = pos[s]
-            t0 = self.clock()
-            with self._mesh_ctx():
-                next_tok, cache, toks = self._decode(
-                    self.params, jnp.asarray(tok), self._slot_cache,
-                    self.kv.page_table, jnp.asarray(dpos),
-                    jnp.asarray(dlen), jnp.asarray(cow_src),
-                    jnp.asarray(cow_dst), block)
-        else:
-            t0 = self.clock()
-            with self._mesh_ctx():
-                next_tok, cache, toks = self._decode(
-                    self.params, jnp.asarray(tok), self._slot_cache,
-                    jnp.asarray(pos), jnp.asarray(pos), block)
-        self._slot_cache = cache
-        toks_np = np.asarray(toks)                   # [N, slots]
-        last_np = np.asarray(next_tok)               # [slots, 1]
-        dt = self.clock() - t0       # the read-backs synchronize, so dt
-        #                              is the real device+host block wall
-        self.registry["decode_dispatch_s"].observe(dt)
-        if self.obs.enabled:
-            self.obs.complete(DISPATCH_DECODE, t0, dt, track=TRACK_ENGINE,
-                              block=block, slots=len(runnable))
-            for s in runnable:
-                self.obs.complete("decode", t0, dt, track=slot_track(s),
-                                  rid=active[s].rid, block=block)
-        useful = 0
-        for s in runnable:
+                r = active[s]
+                h = min(block,
+                        r.max_new_tokens - len(r.out_tokens),
+                        self.max_len - int(pos[s]))
+                r.out_tokens.extend(int(t) for t in toks_np[:h, s])
+                if r.out_tokens and r.first_token_at <= 0.0:
+                    # Bootstrap-admitted slots emit their first token here.
+                    self._stamp_first_token(r, s)
+                useful = max(useful, h)
+                self.registry["generated"].inc(h)
+                pos[s] = min(int(pos[s]) + block, self.max_len)
+                tok[s, 0] = last_np[s, 0]
+                if (len(r.out_tokens) >= r.max_new_tokens
+                        or pos[s] >= self.max_len):
+                    finished.append(s)
+            self.registry["dispatches"].inc()
+            self.registry["ticks"].inc(useful)
+            self.registry["scan_ticks"].inc(block)
+            self.decode_eff = (0.5 * self.decode_eff
+                               + 0.5 * useful / block)
+        self._retire_all(finished, active, decoding, pos, tok)
+
+    def _provision(self, runnable, active, decoding, pos, tok, ahead):
+        """Resolve pending copy-on-write pairs and allocate the pages each
+        runnable slot's next ``ahead(s)`` positions write.  Returns the
+        slots still runnable and the per-slot ``(cow_src, cow_dst)``
+        physical page pairs (NULL for the rest)."""
+        # Prefix bootstrap: a slot whose next append lands inside a shared
+        # page copies it onto a private one in the dispatch, before the
+        # scan; the re-uploaded table already points at dst.
+        cow_src = np.full(self.slots, NULL_PAGE, np.int32)
+        cow_dst = np.full(self.slots, NULL_PAGE, np.int32)
+        for s in list(runnable):
             r = active[s]
-            h = min(block,
-                    r.max_new_tokens - len(r.out_tokens),
-                    self.max_len - int(pos[s]))
-            r.out_tokens.extend(int(t) for t in toks_np[:h, s])
-            if r.out_tokens and r.first_token_at <= 0.0:
-                # Bootstrap-admitted slots emit their first token here.
-                self._stamp_first_token(r, s)
-            useful = max(useful, h)
-            self.registry["generated"].inc(h)
-            pos[s] = min(int(pos[s]) + block, self.max_len)
-            tok[s, 0] = last_np[s, 0]
-            if (len(r.out_tokens) >= r.max_new_tokens
-                    or pos[s] >= self.max_len):
+            try:
+                if self._cow[s] is not None:
+                    cow_src[s], cow_dst[s] = self.kv.cow_page(
+                        s, self._cow[s])
+                    self._cow[s] = None
+                    self.registry["cow_copies"].inc()
+                    # The slot's reference moved off the shared src:
+                    # refresh its eviction entry.
+                    self.prefix.page_released(int(cow_src[s]))
+                # Allocate only what the request's remaining budget can
+                # validly read back: positions past it write into
+                # unallocated pages, which route to the NULL page, and
+                # their outputs are discarded.
+                self.kv.ensure(s, min(int(pos[s]) + ahead(s), self.max_len))
+            except RuntimeError as e:
+                # Pool pressure even after eviction — e.g. every page
+                # referenced across slots while a bootstrap COW needs its
+                # one transient extra page.  Fail THIS request (pages
+                # returned exactly once via the refcounted release) and
+                # keep the stream alive — same contract as the chunk path.
+                r.failed = True
+                r.error = str(e)
+                self.registry["rejected"].inc()
                 self._retire(s, r, active, decoding, pos, tok)
-        self.registry["dispatches"].inc()
-        self.registry["ticks"].inc(useful)
-        self.registry["scan_ticks"].inc(block)
+                cow_src[s] = cow_dst[s] = NULL_PAGE
+        runnable = [s for s in runnable
+                    if active[s] is not None and decoding[s]]
+        return runnable, cow_src, cow_dst
+
+    def _retire_all(self, slots, active, decoding, pos, tok) -> None:
+        """Retire the slots a harvest finished, as one host phase, and
+        refresh the pages-in-use gauge."""
+        if slots:
+            with self._span(ENGINE_RETIRE):
+                for s in slots:
+                    self._retire(s, active[s], active, decoding, pos, tok)
         if self.kv is not None:
             self.registry["pages_in_use"].set(self.kv.pages_in_use)
-        self.decode_eff = (0.5 * self.decode_eff
-                           + 0.5 * useful / block)
 
     # ------------------------------------------------ speculative decode
     def _draft(self, r: Request, limit: int) -> List[int]:
@@ -1276,121 +1326,107 @@ class ServingEngine:
         (their window writes route to the NULL page / their outputs are
         discarded, exactly like padded decode slots)."""
         assert self.kv is not None and self._verify is not None
-        runnable = [s for s in range(self.slots)
-                    if active[s] is not None and decoding[s]]
-        drafts: Dict[int, List[int]] = {}
-        caps: Dict[int, int] = {}
-        need = 1
-        for s in runnable:
-            r = active[s]
-            # A slot may deliver at most ``cap`` tokens this dispatch:
-            # its remaining budget, clamped to max_len (positions past
-            # max_len write to the NULL page and verify garbage).
-            caps[s] = min(r.max_new_tokens - len(r.out_tokens),
-                          self.max_len - int(pos[s]))
-            drafts[s] = self._draft(r, caps[s] - 1)
-            need = max(need, len(drafts[s]) + 1)
-        w = next(x for x in self._w_ladder if x >= need)
-        # COW resolution and page provisioning: same contract as the
-        # decode block (allocator failure fails THIS request only).
-        cow_src = np.full(self.slots, NULL_PAGE, np.int32)
-        cow_dst = np.full(self.slots, NULL_PAGE, np.int32)
-        for s in list(runnable):
-            r = active[s]
-            try:
-                if self._cow[s] is not None:
-                    cow_src[s], cow_dst[s] = self.kv.cow_page(
-                        s, self._cow[s])
-                    self._cow[s] = None
-                    self.registry["cow_copies"].inc()
-                    self.prefix.page_released(int(cow_src[s]))
-                self.kv.ensure(s, min(int(pos[s]) + w, self.max_len))
-            except RuntimeError as e:
-                r.failed = True
-                r.error = str(e)
-                self.registry["rejected"].inc()
-                self._retire(s, r, active, decoding, pos, tok)
-                cow_src[s] = cow_dst[s] = NULL_PAGE
-        runnable = [s for s in runnable
-                    if active[s] is not None and decoding[s]]
-        if not runnable:
-            return
-        toks = np.zeros((self.slots, w), np.int32)
-        dpos = np.full(self.slots, self.kv.extent, np.int32)
-        dlen = np.zeros(self.slots, np.int32)
-        for s in runnable:
-            toks[s, 0] = tok[s, 0]
-            d = drafts[s]
-            toks[s, 1:1 + len(d)] = d
-            dpos[s] = pos[s]
-            dlen[s] = pos[s]
-        t0 = self.clock()
-        with self._mesh_ctx():
-            greedy, cache = self._verify(
-                self.params, jnp.asarray(toks), self._slot_cache,
-                self.kv.page_table, jnp.asarray(dpos), jnp.asarray(dlen),
-                jnp.asarray(cow_src), jnp.asarray(cow_dst))
-        self._slot_cache = cache
-        g = np.asarray(greedy)                       # [slots, W]
-        dt = self.clock() - t0
-        self.registry["verify_dispatch_s"].observe(dt)
-        if self.obs.enabled:
-            self.obs.complete(DISPATCH_VERIFY, t0, dt, track=TRACK_ENGINE,
-                              window=w, slots=len(runnable))
+        with self._span(ENGINE_DECODE_PREP):
+            runnable = [s for s in range(self.slots)
+                        if active[s] is not None and decoding[s]]
+            drafts: Dict[int, List[int]] = {}
+            caps: Dict[int, int] = {}
+            need = 1
             for s in runnable:
-                self.obs.complete("verify", t0, dt, track=slot_track(s),
-                                  rid=active[s].rid, window=w,
-                                  drafts=len(drafts[s]))
-        useful = 0
-        filled = 0
-        for s in runnable:
-            r = active[s]
-            d = drafts[s]
-            cap = caps[s]
-            # Row i's output is the model's next token after consuming
-            # toks[s, :i+1]; draft i is accepted while it EQUALS the
-            # previous row's output — i.e. while the window tracks what
-            # plain greedy decode would have produced anyway.  (A pad
-            # token that happens to match is accepted too: it IS the
-            # correct greedy token.)
-            a = 0
-            while (a < w - 1 and a + 1 < cap
-                   and int(toks[s, a + 1]) == int(g[s, a])):
-                a += 1
-            delivered = a + 1                        # y0..ya
-            r.out_tokens.extend(int(g[s, i]) for i in range(delivered))
-            if r.first_token_at <= 0.0:
-                self._stamp_first_token(r, s)
-            self.registry["generated"].inc(delivered)
-            self.registry["spec_tokens"].inc(delivered)
-            self.registry["draft_tokens"].inc(len(d))
-            self.registry["accepted_tokens"].inc(min(a, len(d)))
-            useful = max(useful, delivered)
-            filled += delivered
-            pos[s] = int(pos[s]) + delivered
-            tok[s, 0] = int(g[s, a])
-            # The verify window appended K/V at pos..pos+W-1; positions
-            # past the new write head are stale.  Wholly-stale pages are
-            # returned now (freshly allocated and exclusively owned by
-            # construction — rollback_extent asserts it); the stale tail
-            # INSIDE the kept last page is masked by length and
-            # overwritten as the slot advances.
-            dropped = self.kv.rollback_extent(s, int(pos[s]))
-            if dropped:
-                self.registry["rollbacks"].inc()
-                self.registry["rollback_pages"].inc(dropped)
-            if self._debug_check_pages:
-                self.kv.assert_page_accounting()
-            if (len(r.out_tokens) >= r.max_new_tokens
-                    or pos[s] >= self.max_len):
-                self._retire(s, r, active, decoding, pos, tok)
-        self.registry["dispatches"].inc()
-        self.registry["verify_dispatches"].inc()
-        self.registry["ticks"].inc(useful)
-        self.registry["scan_ticks"].inc(w)
-        self.registry["pages_in_use"].set(self.kv.pages_in_use)
-        # The decode-pressure EMA counts ACCEPTED tokens per verify row,
-        # not scan ticks — a rejected draft row is wasted capacity
-        # exactly like a wasted scan tick.
-        self.decode_eff = (0.5 * self.decode_eff
-                           + 0.5 * filled / (w * len(runnable)))
+                r = active[s]
+                # A slot may deliver at most ``cap`` tokens this dispatch:
+                # its remaining budget, clamped to max_len (positions past
+                # max_len write to the NULL page and verify garbage).
+                caps[s] = min(r.max_new_tokens - len(r.out_tokens),
+                              self.max_len - int(pos[s]))
+                drafts[s] = self._draft(r, caps[s] - 1)
+                need = max(need, len(drafts[s]) + 1)
+            w = next(x for x in self._w_ladder if x >= need)
+            # COW resolution and page provisioning: same contract as the
+            # decode block (allocator failure fails THIS request only).
+            runnable, cow_src, cow_dst = self._provision(
+                runnable, active, decoding, pos, tok, lambda s: w)
+            if not runnable:
+                return
+            toks = np.zeros((self.slots, w), np.int32)
+            dpos = np.full(self.slots, self.kv.extent, np.int32)
+            dlen = np.zeros(self.slots, np.int32)
+            for s in runnable:
+                toks[s, 0] = tok[s, 0]
+                d = drafts[s]
+                toks[s, 1:1 + len(d)] = d
+                dpos[s] = pos[s]
+                dlen[s] = pos[s]
+        with self._span(DISPATCH_VERIFY, window=w,
+                        slots=len(runnable)) as sp:
+            with self._span(ENGINE_VERIFY_ENQUEUE), self._mesh_ctx():
+                greedy, cache = self._verify(
+                    self.params, jnp.asarray(toks), self._slot_cache,
+                    self.kv.page_table, jnp.asarray(dpos),
+                    jnp.asarray(dlen), jnp.asarray(cow_src),
+                    jnp.asarray(cow_dst))
+                self._slot_cache = cache
+            with self._span(WAIT_VERIFY):
+                g = np.asarray(greedy)                   # [slots, W]
+        with self._span(ENGINE_HARVEST):
+            self.registry["verify_dispatch_s"].observe(sp.dur)
+            if self.obs.enabled:
+                for s in runnable:
+                    self.obs.complete("verify", sp.t0, sp.dur,
+                                      track=slot_track(s),
+                                      rid=active[s].rid, window=w,
+                                      drafts=len(drafts[s]))
+            useful = 0
+            filled = 0
+            finished = []
+            for s in runnable:
+                r = active[s]
+                d = drafts[s]
+                cap = caps[s]
+                # Row i's output is the model's next token after consuming
+                # toks[s, :i+1]; draft i is accepted while it EQUALS the
+                # previous row's output — i.e. while the window tracks
+                # what plain greedy decode would have produced anyway.  (A
+                # pad token that happens to match is accepted too: it IS
+                # the correct greedy token.)
+                a = 0
+                while (a < w - 1 and a + 1 < cap
+                       and int(toks[s, a + 1]) == int(g[s, a])):
+                    a += 1
+                delivered = a + 1                        # y0..ya
+                r.out_tokens.extend(int(g[s, i]) for i in range(delivered))
+                if r.first_token_at <= 0.0:
+                    self._stamp_first_token(r, s)
+                self.registry["generated"].inc(delivered)
+                self.registry["spec_tokens"].inc(delivered)
+                self.registry["draft_tokens"].inc(len(d))
+                self.registry["accepted_tokens"].inc(min(a, len(d)))
+                useful = max(useful, delivered)
+                filled += delivered
+                pos[s] = int(pos[s]) + delivered
+                tok[s, 0] = int(g[s, a])
+                # The verify window appended K/V at pos..pos+W-1; positions
+                # past the new write head are stale.  Wholly-stale pages
+                # are returned now (freshly allocated and exclusively
+                # owned by construction — rollback_extent asserts it); the
+                # stale tail INSIDE the kept last page is masked by length
+                # and overwritten as the slot advances.
+                dropped = self.kv.rollback_extent(s, int(pos[s]))
+                if dropped:
+                    self.registry["rollbacks"].inc()
+                    self.registry["rollback_pages"].inc(dropped)
+                if self._debug_check_pages:
+                    self.kv.assert_page_accounting()
+                if (len(r.out_tokens) >= r.max_new_tokens
+                        or pos[s] >= self.max_len):
+                    finished.append(s)
+            self.registry["dispatches"].inc()
+            self.registry["verify_dispatches"].inc()
+            self.registry["ticks"].inc(useful)
+            self.registry["scan_ticks"].inc(w)
+            # The decode-pressure EMA counts ACCEPTED tokens per verify
+            # row, not scan ticks — a rejected draft row is wasted
+            # capacity exactly like a wasted scan tick.
+            self.decode_eff = (0.5 * self.decode_eff
+                               + 0.5 * filled / (w * len(runnable)))
+        self._retire_all(finished, active, decoding, pos, tok)

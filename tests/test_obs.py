@@ -24,8 +24,10 @@ import numpy as np
 import pytest
 
 from repro.obs import (
+    DEVICE_WAITS,
     DISPATCH_PREFILL_CHUNK,
     DISPATCH_VERIFY,
+    HOST_PHASES,
     NULL_RECORDER,
     Counter,
     Gauge,
@@ -49,6 +51,7 @@ from repro.obs import (
     prometheus_text,
     resolve_recorder,
     slot_track,
+    TRACK_ENGINE,
     validate_chrome_trace,
 )
 
@@ -415,6 +418,37 @@ def test_engine_burst_timeline_matches_counters():
     assert [r.out_tokens for r in reqs] == [r.out_tokens
                                             for r in reqs_off]
     assert off.obs.events == () and not off.obs.enabled
+
+
+def test_engine_host_phases_reach_the_profiler_and_the_recorder(tmp_path):
+    """Each host phase is emitted once to both sinks: the engine spans on
+    the profiler's timeline carry the names of the recorder's engine-track
+    spans, and the first-token mark is the recorder's instant."""
+    import jax
+
+    cfg, eng = _engine(telemetry=True, chunked=True, prefill_chunk=16,
+                       speculative=True, draft_len=4)
+    prompts = _burst_prompts(cfg)
+    eng.generate([p.copy() for p in prompts], max_new_tokens=6)  # compiles
+    eng.obs.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    eng.generate([p.copy() for p in prompts], max_new_tokens=6)
+    jax.profiler.stop_trace()
+    pd = jax.profiler.ProfileData.from_file(
+        str(next(tmp_path.rglob("*.xplane.pb"))))
+    profiled = {e.name for plane in pd.planes if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events
+                if e.name.split(".")[0] in ("engine", "wait", "dispatch",
+                                            "req")}
+    recorded = {e.name for e in eng.obs.events
+                if e.kind == "span" and e.track == TRACK_ENGINE}
+    assert profiled - {REQ_FIRST_TOKEN} == recorded
+    assert eng.obs.count(REQ_FIRST_TOKEN) == len(prompts)
+    assert set(HOST_PHASES + DEVICE_WAITS) & recorded >= {
+        "engine.admit", "engine.chunk_stage", "dispatch.prefill_chunk",
+        "wait.first_token", "engine.decode_prep", "engine.verify_enqueue",
+        "wait.verify", "engine.harvest", "engine.retire"}
+    assert REQ_FIRST_TOKEN in profiled
 
 
 @pytest.mark.slow

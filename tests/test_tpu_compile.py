@@ -6,7 +6,8 @@ installed even where no chip is attached.  Each case lowers one kernel in
 bf16 at the serving shapes the engine dispatches (8 slots, 2048-token
 slots of 16-token pages, 128-token prefill chunks, the plan's 128 blocks)
 against one chip of a described ``v5e:2x2`` topology, and checks that the
-compiled program holds the Pallas kernel.
+compiled program holds the Pallas kernel under its own name.  A decode
+step compiled the same way names its layers in its ops' metadata.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and xdist workers each import this
@@ -15,6 +16,9 @@ so every compile stays in that process.
 """
 
 from __future__ import annotations
+
+import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +124,10 @@ def _ffn(t: int, w8: bool):
     return fn, shapes
 
 
+# The ``kernel_metadata`` name each case's kernel carries, by case prefix.
+KERNEL = {"paged": "paged_attention", "flash": "flash_attention",
+          "rmsnorm": "rmsnorm_matmul", "streamed": "streamed_ffn"}
+
 CASES = {
     "paged_decode": lambda: _paged(verify=False, quant=False),
     "paged_decode_int8": lambda: _paged(verify=False, quant=True),
@@ -147,5 +155,39 @@ def test_main_path_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = CASES[name]()
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    kernel = KERNEL[name.split("_")[0]]
+    assert re.search(r'kernel_metadata=\{\s*"kernel":\s*"%s"\s*\}' % kernel,
+                     text), kernel
+
+
+def test_decode_step_names_its_layers_for_v5e(one_chip):
+    """A paged decode step (reduced widths, eager) compiled for the chip
+    keeps each layer's ``named_scope`` in its ops' ``op_name``."""
+    from repro.models import abstract_params, decode_step
+    from repro.serving.kv_cache import paged_cache_defs
+
+    cfg = dataclasses.replace(CFG.reduced(), dtype="bfloat16")
+    slots, max_len, page = 2, 64, 16
+
+    def shape(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(shape, abstract_params(cfg))
+    cache = jax.tree.map(shape, paged_cache_defs(cfg, slots, max_len, page),
+                         is_leaf=lambda x: hasattr(x, "shape"))
+    tok = jax.ShapeDtypeStruct((slots, 1), I32, sharding=one_chip)
+    vec = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
+    table = jax.ShapeDtypeStruct((slots, max_len // page), I32,
+                                 sharding=one_chip)
+
+    def step(p, t, c, pos, tbl):
+        return decode_step(p, cfg, t, c, pos, pos, page_table=tbl)
+
+    text = jax.jit(step).lower(params, tok, cache, vec, table).compile(
+        ).as_text()
+    names = " ".join(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("embed", "layers", "qkv", "kv_append", "attention",
+                  "out_proj", "ffn", "final_norm", "lm_head", "argmax"):
+        assert f"/{scope}/" in names, scope
