@@ -27,6 +27,7 @@ from ..core.itensor import dtype_bytes
 from ..core.platforms import Platform
 from ..core.stream_plan import KernelChoice, StreamPlan
 from ..kernels.common import LANE, pick_block, round_up
+from ..kernels.paged_attention import pages_per_block
 from .diagnostics import Diagnostic
 
 # Every implementation name a KernelChoice may carry -> the block names
@@ -118,13 +119,20 @@ def vmem_estimate(cfg: ModelConfig, plan: StreamPlan, stage: str,
         bkv = eff(kv_len, "block_kv", kv_len)
         return (bq + 2 * bkv) * dp * dt + bq * (dp + 2) * 4
     if impl in ("paged_attention", "verify_attention"):
+        # Per slot: double-buffered K and V blocks (pages_per_block pages
+        # of every kv head on the shard), the q and output blocks, the
+        # f32 running (m, l, acc), and one K/V block as f32 while it folds.
         dp = round_up(cfg.head_dim_, LANE)
+        hkv = max(1, cfg.num_kv_heads
+                  // _shard_div(choice, mesh, "kv_heads"))
         g = max(1, cfg.num_heads // max(1, cfg.num_kv_heads))
         rows = g
         if impl == "verify_attention":
             rows = g * plan.verify_window(plan.decode_page_size())
         ps = max(1, choice.block("page_size", 16))
-        return rows * dp * dt + 2 * ps * dp * dt + rows * (dp + 2) * 4
+        bt = pages_per_block(ps, -(-kv_len // ps)) * ps
+        return (4 * hkv * bt * dp * dt + 2 * hkv * rows * dp * dt
+                + hkv * rows * (dp + 2) * 4 + 2 * hkv * bt * dp * 4)
     if impl == "mamba2_scan":
         chunk = eff(tokens, "chunk", tokens)
         return 4.0 * chunk * max(cfg.d_inner, 1) * dt

@@ -125,6 +125,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import ModelConfig
 from ..distributed.context import use_mesh
+from ..kernels.paged_attention import live_page_span
 from ..models import (decode_step, init_cache, prefill, resolve_plan,
                       supports_chunked_prefill, supports_speculative,
                       verify_step)
@@ -421,6 +422,13 @@ class ServingEngine:
                 cfg, slots=batch_slots, max_len=max_len,
                 page_size=page_size, mesh=mesh, obs=self.obs)
             self._slot_cache = self.kv.init_cache()
+            # The window of the paged decode kernel's layers, for the
+            # ``paged_attn_pages`` count: that of the first layer kind the
+            # plan runs the kernel in; None when no layer runs it.
+            self._kernel_window = next(
+                (cfg.sliding_window if kind == "local_attn" else 0
+                 for kind, lp in (plan.layers if plan is not None else ())
+                 if lp.decode_attn.fused), None)
 
             def _prefill_into(p, batch, slot_cache, slot, pages):
                 self._traces["prefill"] += 1
@@ -613,6 +621,12 @@ class ServingEngine:
             ("prefix_hit_pages", "prompt pages served from prefix cache"),
             ("prompt_pages", "prompt pages needed by admitted requests"),
             ("cow_copies", "copy-on-write page copies"),
+            ("paged_attn_pages",
+             "pages one layer's paged decode kernel streamed, over ticks "
+             "and slots"),
+            ("paged_attn_grid_pages",
+             "scan ticks x slots x table pages: what a walk of every "
+             "slot's whole table would stream"),
             ("prefix_bootstraps", "fully-cached prompts decode-bootstrapped"),
             ("prefix_evictions", "pages evicted from the prefix cache"),
             ("draft_tokens", "speculative draft tokens proposed"),
@@ -1232,9 +1246,27 @@ class ServingEngine:
             self.registry["dispatches"].inc()
             self.registry["ticks"].inc(useful)
             self.registry["scan_ticks"].inc(block)
+            if self.kv is not None and self._kernel_window is not None:
+                self._count_kernel_pages(dlen, block)
             self.decode_eff = (0.5 * self.decode_eff
                                + 0.5 * useful / block)
         self._retire_all(finished, active, decoding, pos, tok)
+
+    def _count_kernel_pages(self, dlen: np.ndarray, block: int) -> None:
+        """Count the pages the paged decode kernel streamed in a dispatch
+        of ``block`` ticks, per layer: at tick t slot s attends through
+        ``dlen[s] + t`` (its query offset), and streams the pages
+        ``live_page_span`` gives it — the bound the kernel's own loop
+        uses.  Beside it, what a walk of every slot's whole table would
+        have streamed."""
+        n_pages = self.kv.pages_per_slot
+        q_off = dlen[None, :] + np.arange(block)[:, None]
+        _, count = live_page_span(q_off, win=1, page_size=self.kv.page_size,
+                                  n_pages=n_pages,
+                                  window=self._kernel_window)
+        self.registry["paged_attn_pages"].inc(int(count.sum()))
+        self.registry["paged_attn_grid_pages"].inc(
+            block * self.slots * n_pages)
 
     def _provision(self, runnable, active, decoding, pos, tok, ahead):
         """Resolve pending copy-on-write pairs and allocate the pages each

@@ -46,6 +46,7 @@ from ..core.itensor import dtype_bytes
 from ..core.platforms import Platform
 from ..core.stream_plan import KernelChoice, StreamPlan
 from ..kernels.common import LANE, interpret_default, pick_block, round_up
+from ..kernels.paged_attention import pages_per_block
 
 # Median-of-k protocol: one warmup dispatch absorbs compilation, then k
 # timed dispatches; the median is robust to a stray scheduling hiccup.
@@ -136,7 +137,8 @@ def analytic_estimate(cfg: ModelConfig, plan: StreamPlan, stage: str,
         dp = round_up(max(1, cfg.head_dim_), LANE)
         hkv = max(1, cfg.num_kv_heads)
         ps = max(1, choice.block("page_size", 16))
-        steps = hkv * _cdiv(s, ps)
+        pages = _cdiv(s, ps)
+        steps = _cdiv(pages, pages_per_block(ps, pages))
         flops = 4.0 * max(1, cfg.num_heads) * s * dp
         stream = 2.0 * hkv * s * dp * dt
     elif impl in ("mamba2_scan", "rwkv6_wkv"):

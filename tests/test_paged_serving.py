@@ -178,43 +178,90 @@ def test_place_prefill_round_trip(rng):
 
 # ------------------------------------------------------ kernel vs eager
 
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])   # MHA and GQA
-@pytest.mark.parametrize("window", [0, 7])
-def test_paged_kernel_matches_eager_decode(hq, hkv, window):
+def paged_kernel_pools(nprng, pages, *, hkv, d, ps, n_pages, dtype):
+    """K/V pools holding ``pages[i]`` pages of slot i at shuffled physical
+    ids (the rest of each table row at the NULL page), plus the dense f32
+    K/V the eager reference reads through the same table.  Returns
+    ``(k_pool, v_pool, scales, table, kc, vc)``; ``scales`` is
+    ``{"k_scale", "v_scale"}`` for int8 pools, else empty."""
+    from repro.serving.kv_cache import gather_pages_dequant
+
+    b = len(pages)
+    shape = (1 + b * n_pages, hkv, ps, d)
+    table = np.full((b, n_pages), NULL_PAGE, np.int32)
+    ids = 1 + nprng.permutation(b * n_pages)
+    for i, n in enumerate(pages):
+        table[i, :n] = ids[i * n_pages:i * n_pages + n]
+    table = jnp.asarray(table)
+    scales = {}
+    if dtype == "int8":
+        k_pool, v_pool = (jnp.asarray(nprng.integers(
+            -127, 128, size=shape), jnp.int8) for _ in range(2))
+        ks, vs = (jnp.asarray(nprng.uniform(
+            0.004, 0.02, size=shape[:2]), jnp.float32) for _ in range(2))
+        scales = {"k_scale": ks, "v_scale": vs}
+        kc = gather_pages_dequant(k_pool, ks, table, layout="bshd")
+        vc = gather_pages_dequant(v_pool, vs, table, layout="bshd")
+    else:
+        k_pool, v_pool = (jnp.asarray(nprng.normal(size=shape).astype(
+            np.float32)).astype(dtype) for _ in range(2))
+        kc = gather_pages(k_pool, table, layout="bshd")
+        vc = gather_pages(v_pool, table, layout="bshd")
+    return k_pool, v_pool, scales, table, kc, vc
+
+
+# Lengths at the stream's edges for 16-token pages and 8-page (128-token)
+# blocks in an 11-page table (not a multiple of the block): an idle slot,
+# one token, exactly a page, exactly a block, one past a block, the
+# whole table.
+RAGGED = (0, 1, 16, 128, 129, 176)
+
+# id: (hq, hkv, head_dim, page_size, max_pages, window, pool dtype,
+# lengths).  The first four (4 pages of 8: a table smaller than a block)
+# keep their original ids.
+DECODE_CASES = {
+    "0-4-4": (4, 4, 16, 8, 4, 0, "bfloat16", (5, 17, 32)),
+    "0-4-2": (4, 2, 16, 8, 4, 0, "bfloat16", (5, 17, 32)),
+    "7-4-4": (4, 4, 16, 8, 4, 7, "bfloat16", (5, 17, 32)),
+    "7-4-2": (4, 2, 16, 8, 4, 7, "bfloat16", (5, 17, 32)),
+    "mha-hd64-bf16": (2, 2, 64, 16, 11, 0, "bfloat16", RAGGED),
+    "gqa-hd128-bf16": (4, 2, 128, 16, 11, 0, "bfloat16", RAGGED),
+    "gqa-hd128-int8": (4, 2, 128, 16, 11, 0, "int8", RAGGED),
+    "mha-hd64-int8-window37": (2, 2, 64, 16, 11, 37, "int8", RAGGED),
+    "gqa-hd64-bf16-window150": (4, 1, 64, 16, 11, 150, "bfloat16", RAGGED),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_CASES), ids=list(DECODE_CASES))
+def test_paged_kernel_matches_eager_decode(case):
     """Pallas paged decode attention == eager contiguous decode attention
-    to 1e-5, across mixed per-slot lengths (bf16 cache, f32 queries)."""
+    to 1e-5 (f32 queries) across ragged per-slot lengths, MHA and GQA,
+    head_dim 64 and 128, bf16 and int8 pools, with and without a sliding
+    window; a slot of length 0 produces zeros."""
     from repro.kernels import paged_decode_attention
     from repro.models.layers import decode_attention
 
-    b, d, ps, n_pages = 3, 16, 8, 4
+    hq, hkv, d, ps, n_pages, window, dtype, lens = DECODE_CASES[case]
     nprng = np.random.default_rng(2)
-    q = jnp.asarray(nprng.normal(size=(b, 1, hq, d)).astype(np.float32))
-    k_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32)
-    ).astype(jnp.bfloat16)
-    v_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32)
-    ).astype(jnp.bfloat16)
-    lengths = np.array([5, 17, 32], np.int32)
-    table = np.zeros((b, n_pages), np.int32)
-    nxt = 1
-    for i in range(b):
-        for j in range(-(-int(lengths[i]) // ps)):
-            table[i, j] = nxt
-            nxt += 1
-    table, lengths = jnp.asarray(table), jnp.asarray(lengths)
+    q = jnp.asarray(nprng.normal(size=(len(lens), 1, hq, d)).astype(
+        np.float32))
+    k_pool, v_pool, scales, table, kc, vc = paged_kernel_pools(
+        nprng, [-(-n // ps) for n in lens], hkv=hkv, d=d, ps=ps,
+        n_pages=n_pages, dtype=dtype)
+    lengths = jnp.asarray(lens, jnp.int32)
 
-    out = paged_decode_attention(q, k_pool, v_pool, table, lengths,
-                                 window=window)
-    kc = gather_pages(k_pool, table, layout="bshd")
-    vc = gather_pages(v_pool, table, layout="bshd")
-    ref = decode_attention(q, kc, vc, lengths, window=window, layout="bshd")
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=1e-5)
-    # Inactive slot (length 0, NULL-page table row): finite zeros.
+    out = np.asarray(paged_decode_attention(
+        q, k_pool, v_pool, table, lengths, window=window, **scales),
+        np.float32)
+    ref = np.asarray(decode_attention(q, kc, vc, lengths, window=window,
+                                      layout="bshd"), np.float32)
+    live = np.asarray(lens) > 0
+    np.testing.assert_allclose(out[live], ref[live], atol=1e-5)
+    assert np.all(out[~live] == 0.0)
+    # Inactive slots (length 0, NULL-page table rows): finite zeros.
     out0 = paged_decode_attention(q, k_pool, v_pool,
                                   jnp.zeros_like(table),
-                                  jnp.zeros((b,), jnp.int32))
+                                  jnp.zeros_like(lengths), **scales)
     assert np.all(np.asarray(out0) == 0.0)
 
 
@@ -305,6 +352,35 @@ def test_engine_fused_paged_attention_matches_eager(rng, arch):
                        decode_block=8).generate(prompts, max_new_tokens=10)
     for a, b in zip(r0, r1):
         assert a.out_tokens == b.out_tokens, f"request {a.rid} diverged"
+
+
+def test_engine_counts_paged_kernel_pages(rng):
+    """``paged_attn_pages`` equals the live pages of every decode tick,
+    counted independently from the requests' own positions (every slot
+    decodes in every dispatch here); ``paged_attn_grid_pages`` is
+    ``scan_ticks`` x slots x table pages, what a walk of whole tables
+    would stream."""
+    cfg = dataclasses.replace(get_config("llama3-8b").reduced(),
+                              dtype="float32", use_fused_kernels=True)
+    params = init_params(rng, cfg)
+    nprng = np.random.default_rng(6)
+    prompts = [nprng.integers(1, cfg.vocab_size, n, dtype=np.int32)
+               for n in (5, 12, 23)]
+    eng = ServingEngine(cfg, params, batch_slots=3, max_len=64,
+                        decode_block=4)
+    reqs = eng.generate(prompts, max_new_tokens=9)
+    assert all(len(r.out_tokens) == 9 for r in reqs)
+    m = eng.metrics
+    ps, n_pages = eng.kv.page_size, eng.kv.pages_per_slot
+    ticks = int(m["scan_ticks"])
+    # Tick t of the decode scan appends the token at position len + t and
+    # attends through it: ceil((len + t + 1) / page_size) pages (the
+    # second slot crosses a page boundary mid-dispatch).
+    want = sum(min(-(-(len(p) + t + 1) // ps), n_pages)
+               for p in prompts for t in range(ticks))
+    assert ticks == 8 and ps == 16 and m["paged_attn_pages"] == want
+    assert m["paged_attn_grid_pages"] == ticks * 3 * n_pages
+    assert m["paged_attn_pages"] < m["paged_attn_grid_pages"]
 
 
 @pytest.mark.slow

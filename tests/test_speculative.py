@@ -109,42 +109,54 @@ def test_verify_attention_matches_per_row_decode(hq, hkv, window):
                                    np.asarray(ref), atol=1e-6)
 
 
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
-@pytest.mark.parametrize("window", [0, 7])
-def test_paged_verify_kernel_matches_eager(hq, hkv, window):
+# id: (hq, hkv, head_dim, page_size, max_pages, window, pool dtype,
+# q_off per slot), W = 4.  The first four (f32 pools, 5 pages of 8) keep
+# their original ids; the rest put windows at the edges of 16-token pages
+# and 8-page blocks in an 11-page table: rows 1-4, across a page, ending
+# exactly at a block, one past it, the last window of the table.
+VERIFY_OFFSETS = (0, 14, 124, 125, 172)
+VERIFY_CASES = {
+    "0-4-4": (4, 4, 16, 8, 5, 0, "float32", (5, 17, 33)),
+    "0-4-2": (4, 2, 16, 8, 5, 0, "float32", (5, 17, 33)),
+    "7-4-4": (4, 4, 16, 8, 5, 7, "float32", (5, 17, 33)),
+    "7-4-2": (4, 2, 16, 8, 5, 7, "float32", (5, 17, 33)),
+    "mha-hd64-bf16": (2, 2, 64, 16, 11, 0, "bfloat16", VERIFY_OFFSETS),
+    "gqa-hd128-int8": (4, 2, 128, 16, 11, 0, "int8", VERIFY_OFFSETS),
+    "gqa-hd64-int8-window37": (4, 2, 64, 16, 11, 37, "int8",
+                               VERIFY_OFFSETS),
+}
+
+
+@pytest.mark.parametrize("case", list(VERIFY_CASES), ids=list(VERIFY_CASES))
+def test_paged_verify_kernel_matches_eager(case):
     """Pallas paged verify kernel == eager verify attention to 1e-5
-    through the page-table indirection, mixed per-slot offsets."""
+    through the page-table indirection, mixed per-slot offsets, MHA and
+    GQA, head_dim 64 and 128, f32, bf16 and int8 pools, with and without
+    a sliding window."""
     from repro.kernels import paged_verify_attention
     from repro.models.layers import verify_attention
-    from repro.serving.kv_cache import gather_pages
 
-    b, d, ps, n_pages, w = 3, 16, 8, 5, 4
+    from test_paged_serving import paged_kernel_pools
+
+    hq, hkv, d, ps, n_pages, window, dtype, offs = VERIFY_CASES[case]
+    w = 4
     nprng = np.random.default_rng(4)
-    q = jnp.asarray(nprng.normal(size=(b, w, hq, d)).astype(np.float32))
-    k_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32))
-    v_pool = jnp.asarray(nprng.normal(
-        size=(1 + b * n_pages, hkv, ps, d)).astype(np.float32))
-    q_off = np.array([5, 17, 33], np.int32)
-    table = np.zeros((b, n_pages), np.int32)
-    nxt = 1
-    for i in range(b):
-        for j in range(-(-(int(q_off[i]) + w) // ps)):
-            table[i, j] = nxt
-            nxt += 1
-    table, q_off = jnp.asarray(table), jnp.asarray(q_off)
+    q = jnp.asarray(nprng.normal(size=(len(offs), w, hq, d)).astype(
+        np.float32))
+    k_pool, v_pool, scales, table, kc, vc = paged_kernel_pools(
+        nprng, [-(-(o + w) // ps) for o in offs], hkv=hkv, d=d, ps=ps,
+        n_pages=n_pages, dtype=dtype)
+    q_off = jnp.asarray(offs, jnp.int32)
 
     out = paged_verify_attention(q, k_pool, v_pool, table, q_off,
-                                 window=window)
-    kc = gather_pages(k_pool, table, layout="bshd")
-    vc = gather_pages(v_pool, table, layout="bshd")
+                                 window=window, **scales)
     ref = verify_attention(q, kc, vc, q_off, window=window, layout="bshd")
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=1e-5)
     # Idle slots (offset 0, NULL table row): finite zeros, no NaNs.
     out0 = paged_verify_attention(q, k_pool, v_pool,
                                   jnp.zeros_like(table),
-                                  jnp.zeros((b,), jnp.int32))
+                                  jnp.zeros_like(q_off), **scales)
     assert np.all(np.isfinite(np.asarray(out0)))
 
 

@@ -1,13 +1,15 @@
-"""Main-path kernels compile for a described TPU v5e at qwen3-0.6b widths.
+"""Main-path kernels compile for a described TPU v5e at qwen3-0.6b widths,
+and the paged decode kernel at qwen1.5-0.5b's (head_dim 64) as well.
 
 Interpret mode (every other kernel test) cannot see misaligned blocks,
 layouts Mosaic refuses, or VMEM overruns; the TPU compiler can, and it is
 installed even where no chip is attached.  Each case lowers one kernel in
-bf16 at the serving shapes the engine dispatches (8 slots, 2048-token
-slots of 16-token pages, 128-token prefill chunks, the plan's 128 blocks)
-against one chip of a described ``v5e:2x2`` topology, and checks that the
-compiled program holds the Pallas kernel under its own name.  A decode
-step compiled the same way names its layers in its ops' metadata.
+bf16 (int8 where named) at the serving shapes the engine dispatches
+(2048-token slots of 16-token pages, 128-token prefill chunks, the plan's
+128 blocks) against one chip of a described ``v5e:2x2`` topology, and
+checks that the compiled program holds the Pallas kernel under its own
+name.  A decode step compiled the same way names its layers in its ops'
+metadata.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and xdist workers each import this
@@ -31,9 +33,8 @@ from repro.kernels import (paged_decode_attention, paged_verify_attention,
 from repro.kernels.ops import flash_attention
 
 CFG = get_config("qwen3-0.6b")
-SLOTS, MAX_LEN, PAGE, CHUNK, BLOCK, DRAFT_W = 8, 2048, 16, 128, 128, 4
+MAX_LEN, PAGE, CHUNK, BLOCK, DRAFT_W = 2048, 16, 128, 128, 4
 N_PAGES = MAX_LEN // PAGE
-POOL_PAGES = 1 + SLOTS * N_PAGES          # +1: the NULL page
 
 BF16, I8, F32, I32 = jnp.bfloat16, jnp.int8, jnp.float32, jnp.int32
 
@@ -60,16 +61,23 @@ def one_chip(topo):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _paged(verify: bool, quant: bool):
-    hq, hkv, d = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim_
+# The paged kernel at the benchmark's two configurations: qwen3-0.6b's
+# chat engine (16 slots, GQA 16/8, head_dim 128) and qwen1.5-0.5b's (8
+# slots, MHA 16/16, head_dim 64), each over 128-page tables.
+PAGED_SHAPES = {"": (16, 16, 8, 128), "hd64": (8, 16, 16, 64)}
+
+
+def _paged(verify: bool, quant: bool, shape: str = ""):
+    slots, hq, hkv, d = PAGED_SHAPES[shape]
+    pool_pages = 1 + slots * N_PAGES          # +1: the NULL page
     w = DRAFT_W if verify else 1
     pool_dtype = I8 if quant else BF16
-    shapes = [((SLOTS, w, hq, d), BF16),
-              ((POOL_PAGES, hkv, PAGE, d), pool_dtype),
-              ((POOL_PAGES, hkv, PAGE, d), pool_dtype),
-              ((SLOTS, N_PAGES), I32), ((SLOTS,), I32)]
+    shapes = [((slots, w, hq, d), BF16),
+              ((pool_pages, hkv, PAGE, d), pool_dtype),
+              ((pool_pages, hkv, PAGE, d), pool_dtype),
+              ((slots, N_PAGES), I32), ((slots,), I32)]
     if quant:
-        shapes += [((POOL_PAGES, hkv), F32)] * 2
+        shapes += [((pool_pages, hkv), F32)] * 2
     kernel = paged_verify_attention if verify else paged_decode_attention
 
     def fn(q, kp, vp, tbl, off, *scales):
@@ -133,6 +141,10 @@ CASES = {
     "paged_decode_int8": lambda: _paged(verify=False, quant=True),
     "paged_verify": lambda: _paged(verify=True, quant=False),
     "paged_verify_int8": lambda: _paged(verify=True, quant=True),
+    "paged_decode_hd64": lambda: _paged(False, False, "hd64"),
+    "paged_decode_hd64_int8": lambda: _paged(False, True, "hd64"),
+    "paged_verify_hd64": lambda: _paged(True, False, "hd64"),
+    "paged_verify_hd64_int8": lambda: _paged(True, True, "hd64"),
     "flash_offset": lambda: _flash(quant=False),
     "flash_offset_int8": lambda: _flash(quant=True),
 }
@@ -160,6 +172,21 @@ def test_main_path_kernel_compiles_for_v5e(name, one_chip):
     kernel = KERNEL[name.split("_")[0]]
     assert re.search(r'kernel_metadata=\{\s*"kernel":\s*"%s"\s*\}' % kernel,
                      text), kernel
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_decode_reads_hd64_pools_unpadded_for_v5e(quant, one_chip):
+    """At head_dim 64 the paged decode kernel reads the pools as stored:
+    neither the lowered nor the compiled program pads them (or q) to 128
+    lanes."""
+    fn, shapes = _paged(verify=False, quant=quant, shape="hd64")
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert "pad" not in lowered.as_text()
+    compiled = lowered.compile().as_text()
+    assert "tpu_custom_call" in compiled
+    assert not re.search(r"\spad\(", compiled)
 
 
 def test_decode_step_names_its_layers_for_v5e(one_chip):
