@@ -1,12 +1,13 @@
 """What decides ``correct``: served tokens against the float32 reference.
 
 After the window, a sample of the finished requests, drawn from the seed
-and always holding the longest, is run once through the plain reference
-(``reference/dense.py``) over prompt + served tokens.  At each served
-position the reference's logits give the gap by which the served token's
-logit lies below the reference's best.  Greedy decoding in bfloat16 flips
-only near-ties, a few per cent of positions by a few hundredths; a wrong
-cache page, position or layer leaves gaps the size of the logits' spread.
+and always holding the longest, is run once through the configuration's
+plain reference (``bench/reference/<model_type>.py``) over prompt +
+served tokens.  At each served position the reference's logits give the
+gap by which the served token's logit lies below the reference's best.
+Greedy decoding in bfloat16 flips only near-ties, a few per cent of
+positions by a few hundredths; a wrong cache page, position or layer
+leaves gaps the size of the logits' spread.
 
 The number compared is the mean gap over the sample's served positions.
 The widest gap was the first choice, but it does not separate the
@@ -23,20 +24,14 @@ tokens it reads the gap of the token the int8 reference ranks first.
 
 from __future__ import annotations
 
-import importlib
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import dense
+from bench.reference import reference_module
 from bench.weights import published
-
-
-def reference_module(cfg: Dict):
-    """``bench/reference/<model_type>.py`` of a configuration file."""
-    return importlib.import_module(f"bench.reference.{cfg['model_type']}")
 
 
 def sample(reqs: Sequence, k: int, rng: np.random.Generator) -> List:
@@ -72,19 +67,18 @@ class Reference:
 
     def __init__(self, cfg: Dict, width: int, n_served: int):
         self.cfg = cfg
-        self.width = -(-width // dense.Q_BLOCK) * dense.Q_BLOCK
+        self.width = width
         self.n = n_served
-        qk = reference_module(cfg).QK_NORM
+        mod = reference_module(cfg)
 
         def gaps(params, toks, at, served, quant):
             w = published(params, cfg)
-            ref = dense.forward_logits(w, cfg, toks, at, qk_norm=qk)
+            ref = mod.forward_logits(w, cfg, toks, at)
             best = jnp.max(ref, axis=-1)
             if quant is None:
                 pick = served
             else:
-                low = dense.forward_logits(w, cfg, toks, at, qk_norm=qk,
-                                           quant=quant)
+                low = mod.forward_logits(w, cfg, toks, at, quant=quant)
                 pick = jnp.argmax(low, axis=-1)
             return best - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
 

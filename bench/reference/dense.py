@@ -10,29 +10,37 @@ Written from the published equations (Hugging Face ``modeling_qwen2`` and
     x  = x + softmax(q k^T / sqrt(hd) + causal) v Wo  # GQA: kv = q // group
     h  = RMSNorm(x) * w_post
     x  = x + (silu(h Wg) * (h Wu)) Wd
-    logits = (RMSNorm(x) * w_norm) E^T                   # tied embedding E
+    logits = (RMSNorm(x) * w_norm) W_head                # W_head = E^T if tied
 
 Every matrix product runs at ``Precision.HIGHEST`` (on a TPU an f32 dot
-otherwise runs in bfloat16 passes).  Attention is computed in blocks of
-queries so a 4,096-token row fits beside the weights.
+otherwise runs in bfloat16 passes).  Weights arrive in the served dtype
+and each layer is upcast to float32 inside the layer scan (``upcast``),
+so one layer, not the whole model, is held in float32 beside them.
+Attention is computed in blocks of queries so a 4,096-token row fits
+beside the weights.
 
 ``quant="int8"`` is the control: every linear layer (the head included)
 takes symmetric int8 operands, activations per row and weights per output
 column, accumulated exactly.  It is the precision step below the
 bfloat16 the configurations state.
 
-Weights come as a ``Published`` tree in the names above; this module knows
-nothing of the program's own parameter layout.
+This module gives the contract of ``bench/reference/__init__.py`` for
+such a decoder, which ``qwen2.py`` and ``qwen3.py`` take: ``published``
+is its one function that reads the program's parameter layout; the
+forward sees only the names above.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bench import weights
+from bench.reference import Layer
 
 HIGHEST = lax.Precision.HIGHEST
 Q_BLOCK = 512
@@ -74,14 +82,14 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """q [S, Hq, D], k/v [S, Hkv, D] -> [S, Hq, D]; causal, GQA, f32,
-    computed ``Q_BLOCK`` query rows at a time."""
+    computed ``Q_BLOCK`` query rows at a time (fewer where ``S`` is not a
+    multiple of it)."""
     s, hq, d = q.shape
     hkv = k.shape[1]
     g = hq // hkv
     kk = jnp.repeat(k, g, axis=1)                                # [S, Hq, D]
     vv = jnp.repeat(v, g, axis=1)
-    blk = min(Q_BLOCK, s)
-    assert s % blk == 0, (s, blk)
+    blk = math.gcd(Q_BLOCK, s)
     qb = q.reshape(s // blk, blk, hq, d)
     cols = jnp.arange(s)
 
@@ -98,15 +106,29 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     return out.reshape(s, hq, d)
 
 
+def upcast(a: jax.Array) -> jax.Array:
+    """``a`` in float32, exactly.  A bfloat16 value is the upper half of
+    the float32 one, and is widened by its bits, not by a cast: XLA folds
+    a cast into the product it feeds, which then multiplies a bfloat16
+    operand in other passes and rounds differently from the float32
+    product the limits were set with."""
+    if a.dtype != jnp.bfloat16:
+        return a.astype(jnp.float32)
+    bits = lax.bitcast_convert_type(a, jnp.uint16).astype(jnp.uint32)
+    return lax.bitcast_convert_type(bits << 16, jnp.float32)
+
+
 def forward_logits(w: Dict, cfg: Dict, tokens: jax.Array, at: jax.Array, *,
                    qk_norm: bool, quant: Optional[str] = None) -> jax.Array:
     """Logits [len(at), vocab] of one row ``tokens`` [S] at positions ``at``.
 
-    ``w`` holds f32 arrays: ``embed`` [V, d]; ``layers`` stacked over the
-    layer axis (``input_norm``, ``q``, ``k``, ``v``, ``o``, optional
-    ``q_bias``/``k_bias``/``v_bias``, optional ``q_norm``/``k_norm``,
-    ``post_norm``, ``gate``, ``up``, ``down``); ``norm`` [d].
+    ``w`` holds ``embed`` [V, d] and ``head`` [d, V]; ``layers`` stacked
+    over the layer axis (``input_norm``, ``q``, ``k``, ``v``, ``o``,
+    optional ``q_bias``/``k_bias``/``v_bias``, optional ``q_norm``/
+    ``k_norm``, ``post_norm``, ``gate``, ``up``, ``down``); ``norm`` [d].
+    Any float dtype: each is upcast to float32 where it is used.
     """
+    f32 = lambda a: jax.tree.map(upcast, a)   # noqa: E731
     hq = cfg["num_attention_heads"]
     hkv = cfg["num_key_value_heads"]
     hd = cfg.get("head_dim") or cfg["hidden_size"] // hq
@@ -114,9 +136,10 @@ def forward_logits(w: Dict, cfg: Dict, tokens: jax.Array, at: jax.Array, *,
     theta = float(cfg["rope_theta"])
     s = tokens.shape[0]
     positions = jnp.arange(s)
-    x = jnp.take(w["embed"], tokens, axis=0)                     # [S, d]
+    x = f32(jnp.take(w["embed"], tokens, axis=0))                # [S, d]
 
     def layer(x, lw):
+        lw = f32(lw)
         h = rms_norm(x, lw["input_norm"], eps)
         q = linear(h, lw["q"], quant)
         k = linear(h, lw["k"], quant)
@@ -138,5 +161,44 @@ def forward_logits(w: Dict, cfg: Dict, tokens: jax.Array, at: jax.Array, *,
         return x + linear(f, lw["down"], quant), None
 
     x, _ = lax.scan(layer, x, w["layers"])
-    h = rms_norm(jnp.take(x, at, axis=0), w["norm"], eps)       # [n, d]
-    return linear(h, w["embed"].T, quant)
+    h = rms_norm(jnp.take(x, at, axis=0), f32(w["norm"]), eps)  # [n, d]
+    return linear(h, f32(w["head"]), quant)
+
+
+def published(params, cfg: Dict) -> Dict:
+    """The engine's tree of such a decoder in the names ``forward_logits``
+    takes: one homogeneous stack of layers, matrices and biases in the
+    served dtype, norms in float32."""
+    if params["rest"] or len(params["blocks"]) != 1:
+        raise ValueError("expected one homogeneous stack of layers")
+    blk = params["blocks"][0]
+    attn, mlp = blk["attn"], blk["mlp"]
+    layers = {
+        "input_norm": weights.norm(blk["ln1"]["scale"]),
+        "q": attn["wq"], "k": attn["wk"], "v": attn["wv"], "o": attn["wo"],
+        "post_norm": weights.norm(blk["ln2"]["scale"]),
+        "gate": mlp["wg"], "up": mlp["wu"], "down": mlp["wd"],
+    }
+    if "bq" in attn:
+        layers.update(q_bias=attn["bq"], k_bias=attn["bk"],
+                      v_bias=attn["bv"])
+    if "q_norm" in attn:
+        layers.update(q_norm=weights.norm(attn["q_norm"]),
+                      k_norm=weights.norm(attn["k_norm"]))
+    return {"embed": weights.embedding(params, cfg),
+            "head": weights.head(params, cfg),
+            "layers": layers,
+            "norm": weights.norm(params["final_norm"]["scale"])}
+
+
+def layers(cfg: Dict) -> List[Layer]:
+    """Every layer alike: full attention, q/k/v/o and a gated FFN of
+    ``intermediate_size``, each weight read once a tick and used for two
+    operations per token."""
+    d, hq = cfg["hidden_size"], cfg["num_attention_heads"]
+    hkv = cfg["num_key_value_heads"]
+    hd = cfg.get("head_dim") or d // hq
+    ff = cfg["intermediate_size"]
+    n = d * (hq + 2 * hkv) * hd + hq * hd * d + 3 * d * ff
+    return [Layer(window=0, heads=hq, kv_heads=hkv, head_dim=hd, weights=n,
+                  flops=2 * n)] * cfg["num_hidden_layers"]

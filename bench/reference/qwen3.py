@@ -1,11 +1,16 @@
-"""Qwen3 (``model_type: qwen3``): GQA, qk-norm over head_dim, no biases."""
+"""Qwen3 (``model_type: qwen3``): GQA, qk-norm over head_dim, no biases.
+The dense decoder of ``dense.py``."""
+
+from bench.reference import dense
+from bench.reference.dense import layers, published  # noqa: F401
 
 QK_NORM = True
-QKV_BIAS = False
 
 
 def program_fields(cfg: dict) -> dict:
     """``ModelConfig`` fields of the engine that ``config.json`` sets."""
+    if cfg["hidden_act"] != "silu" or cfg["rms_norm_eps"] != 1e-6:
+        raise ValueError("the engine computes silu and RMSNorm eps 1e-6 only")
     return {
         "num_layers": cfg["num_hidden_layers"],
         "d_model": cfg["hidden_size"],
@@ -19,3 +24,8 @@ def program_fields(cfg: dict) -> dict:
         "qkv_bias": bool(cfg["attention_bias"]),
         "tie_embeddings": bool(cfg["tie_word_embeddings"]),
     }
+
+
+def forward_logits(w, cfg, tokens, at, quant=None):
+    return dense.forward_logits(w, cfg, tokens, at, qk_norm=QK_NORM,
+                                quant=quant)

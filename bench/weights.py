@@ -1,22 +1,26 @@
-"""Random served weights from ``--seed``, and their published reading.
+"""Random served weights from ``--seed``, and the engine's conventions.
 
 ``make_params`` draws the engine's parameter tree on the device, in
 bfloat16, in one jitted call: nothing is drawn on the host and no float32
 copy of the tree exists.  The tree follows the engine's layout (shapes from
 ``repro.models.params.abstract_params``), which differs from the published
-one in three conventions, all undone exactly by ``published``:
+one in three conventions, each undone by a reader here:
 
 * RMSNorm weights are stored as ``w - 1`` (the engine multiplies by
-  ``1 + scale``);
-* the input embedding is stored divided by ``sqrt(hidden_size)`` (the
-  engine multiplies by it; 32 for a width of 1,024, a power of two, so
-  the division is exact in bfloat16);
-* the tied embedding is stored twice, as ``embed`` and as the output head
-  ``lm_head`` (its transpose), both padded to a multiple of 256 rows.
+  ``1 + scale``): ``norm``;
+* the input embedding is stored divided by ``sqrt(hidden_size)``, and the
+  engine multiplies each row it takes by that root in the served dtype:
+  ``embedding`` forms the table the same way, so where the root is not a
+  power of two the reference sees the engine's rounding, not the draw's;
+* the output head ``lm_head`` is ``[hidden_size, vocab]``, both it and
+  the embedding padded to a multiple of 256 rows of the vocabulary:
+  ``head`` reads it.  A tied configuration stores its embedding twice,
+  as ``embed`` and as ``lm_head``, the transpose of the table the engine
+  forms from ``embed``, so both roles see one matrix at every width; an
+  untied one draws ``lm_head`` from its own leaf key.
 
-``published`` reads that tree back into the names of ``reference.dense`` in
-float32.  It takes the output head from the embedding, as the tied
-configuration states, not from the engine's ``lm_head`` copy.
+``published`` reads the tree into the names of the configuration's
+reference module (``bench/reference/``), which knows its layers.
 """
 
 from __future__ import annotations
@@ -27,6 +31,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.reference import reference_module
 
 NORM_STD = 0.1      # RMSNorm / qk-norm weights are 1 + N(0, NORM_STD^2)
 BIAS_STD = 0.05     # q/k/v biases are N(0, BIAS_STD^2); at 0.5 the v bias
@@ -49,32 +55,33 @@ def _leaf_name(path) -> str:
 
 def make_params(pcfg, seed: int, init_std: float):
     """The engine's parameter tree for ``pcfg``, bfloat16, on the default
-    device, drawn from ``seed`` in one jitted call; the tied embedding is
-    N(0, ``init_std``^2) (the configuration's ``initializer_range``)."""
+    device, drawn from ``seed`` in one jitted call; the embedding, and an
+    untied output head, are N(0, ``init_std``^2) (the configuration's
+    ``initializer_range``)."""
     from repro.models.params import abstract_params
 
     shapes = abstract_params(pcfg)
     d, v = pcfg.d_model, pcfg.vocab_size
     emb_std = float(init_std)
     root = math.sqrt(d)
-    if root != int(root) or int(root) & (int(root) - 1):
-        raise ValueError(f"hidden_size {d}: sqrt is not a power of two, so "
-                         "the engine's embedding scale is not exact")
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def draw(key):
         keys = jax.random.split(key, len(flat) + 1)
         table = (jax.random.normal(keys[-1], (v, d), jnp.float32)
                  * emb_std).astype(jnp.bfloat16)                    # E
+        stored = table / jnp.bfloat16(root)
+        formed = stored * jnp.bfloat16(root)    # E as the engine embeds
 
         def leaf(i, path, s):
             name = _leaf_name(path)
             if name == "embed":
-                return jnp.zeros(s.shape, jnp.bfloat16).at[:v].set(
-                    table / jnp.bfloat16(root))
+                return jnp.zeros(s.shape, jnp.bfloat16).at[:v].set(stored)
             if name == "lm_head":
-                return jnp.zeros(s.shape, jnp.bfloat16).at[:, :v].set(
-                    table.T)
+                own = formed.T if pcfg.tie_embeddings else (
+                    jax.random.normal(keys[i], (d, v), jnp.float32)
+                    * emb_std).astype(jnp.bfloat16)
+                return jnp.zeros(s.shape, jnp.bfloat16).at[:, :v].set(own)
             if name in NORM_LEAVES:
                 std = NORM_STD
             elif name in BIAS_LEAVES:
@@ -91,30 +98,31 @@ def make_params(pcfg, seed: int, init_std: float):
     return jax.jit(draw)(key)
 
 
+def norm(scale: jax.Array) -> jax.Array:
+    """A norm weight as published, float32, from its stored ``w - 1``."""
+    return 1.0 + jnp.asarray(scale, jnp.float32)
+
+
+def embedding(params, cfg: Dict) -> jax.Array:
+    """The input embedding [vocab, hidden] as the engine forms it, in the
+    served dtype: each stored row times ``sqrt(hidden_size)``, rounded."""
+    e = params["embed"][:cfg["vocab_size"]]
+    return e * jnp.asarray(math.sqrt(cfg["hidden_size"]), e.dtype)
+
+
+def head(params, cfg: Dict) -> jax.Array:
+    """The output head [hidden, vocab] in the served dtype: the engine's
+    ``lm_head``.  Where the configuration ties it, that holds the same
+    numbers as ``embedding`` (``make_params``), and the head is read as
+    the transposed embedding, the operand the product has always had: the
+    same numbers in another layout round the product differently."""
+    if cfg["tie_word_embeddings"]:
+        return embedding(params, cfg).T
+    return params["lm_head"][:, :cfg["vocab_size"]]
+
+
 def published(params, cfg: Dict) -> Dict:
-    """The engine tree read as the published model, in float32 (trace this
-    inside the reference's jit, so nothing is kept).  ``cfg`` is the
-    configuration file."""
-    f32 = lambda a: jnp.asarray(a, jnp.float32)   # noqa: E731
-    v, d = cfg["vocab_size"], cfg["hidden_size"]
-    if params["rest"] or len(params["blocks"]) != 1:
-        raise ValueError("expected one homogeneous stack of layers")
-    blk = params["blocks"][0]
-    attn, mlp = blk["attn"], blk["mlp"]
-    layers = {
-        "input_norm": 1.0 + f32(blk["ln1"]["scale"]),
-        "q": f32(attn["wq"]), "k": f32(attn["wk"]), "v": f32(attn["wv"]),
-        "o": f32(attn["wo"]),
-        "post_norm": 1.0 + f32(blk["ln2"]["scale"]),
-        "gate": f32(mlp["wg"]), "up": f32(mlp["wu"]),
-        "down": f32(mlp["wd"]),
-    }
-    if "bq" in attn:
-        layers.update(q_bias=f32(attn["bq"]), k_bias=f32(attn["bk"]),
-                      v_bias=f32(attn["bv"]))
-    if "q_norm" in attn:
-        layers.update(q_norm=1.0 + f32(attn["q_norm"]),
-                      k_norm=1.0 + f32(attn["k_norm"]))
-    return {"embed": f32(params["embed"][:v]) * math.sqrt(d),
-            "layers": layers,
-            "norm": 1.0 + f32(params["final_norm"]["scale"])}
+    """The engine tree read as the published model by the configuration's
+    reference module (trace this inside the reference's jit, so nothing is
+    kept).  ``cfg`` is the configuration file."""
+    return reference_module(cfg).published(params, cfg)
